@@ -42,6 +42,7 @@ from repro.core.file import FileReader, WriteOptions, write_table
 from repro.core.io_sim import NVME, S3, model_time
 from repro.data import synth
 from repro.obs import Tracer, attribute
+from repro.runtime import enable_compile_cache
 
 ROWS = {"scalar": 200_000, "string": 100_000, "scalar-list": 50_000,
         "string-list": 30_000, "vector": 4_000, "vector-list": 1_500,
@@ -73,9 +74,14 @@ def _git_sha():
 
 def _run_meta() -> dict:
     """Run provenance stamped into every BENCH_*.json: without it the perf
-    trajectory across PRs is a pile of unlabelled numbers."""
+    trajectory across PRs is a pile of unlabelled numbers.  The device says
+    which clock a measured time was taken on."""
+    import jax
+
+    dev = jax.devices()[0]
     return {"git_sha": _git_sha(), "store": STORE_SPEC, "smoke": SMOKE,
-            "traced": TRACER is not None,
+            "traced": TRACER is not None, "platform": dev.platform,
+            "device_kind": dev.device_kind, "device_count": jax.device_count(),
             "timestamp": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime())}
 
 
@@ -1803,6 +1809,7 @@ def _parse_args(argv):
 
 def main() -> None:
     want = _parse_args(sys.argv[1:])
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for fn in ALL:
         tag = fn.__name__.split("_")[0]

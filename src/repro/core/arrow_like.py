@@ -23,8 +23,7 @@ from . import arrays as A
 from . import types as T
 from .encodings_base import EncodedColumn, pad_to
 
-# one codec-selection point for the whole repo: compression.py already
-# resolves zstandard-or-zlib, so Arrow buffers use the exact same pair
+# Arrow buffers share compression.py's zstd compressor/decompressor pair
 from .compression import _ZSTD_C as _C, _ZSTD_D as _D
 
 __all__ = ["encode_arrow", "ArrowReader"]

@@ -22,29 +22,10 @@ import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
+import zstandard as _zstd
 
-try:
-    import zstandard as _zstd
-
-    _ZSTD_C = _zstd.ZstdCompressor(level=1)
-    _ZSTD_D = _zstd.ZstdDecompressor()
-except Exception:
-    # no zstandard on this interpreter: stdlib zlib stands in (same opaque
-    # block-compressor class; only the ratio/speed constants differ)
-    import zlib as _zlib
-
-    _zstd = None
-
-    class _ZlibCompressor:
-        def compress(self, b: bytes) -> bytes:
-            return _zlib.compress(b, 1)
-
-    class _ZlibDecompressor:
-        def decompress(self, b: bytes) -> bytes:
-            return _zlib.decompress(b)
-
-    _ZSTD_C = _ZlibCompressor()
-    _ZSTD_D = _ZlibDecompressor()
+_ZSTD_C = _zstd.ZstdCompressor(level=1)
+_ZSTD_D = _zstd.ZstdDecompressor()
 
 __all__ = [
     "Encoded",

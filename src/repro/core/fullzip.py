@@ -530,12 +530,10 @@ class FullZipReader(ColumnReader):
         matrix is decoded strided — bit-identical to the host
         ``reorder_leaf_rows`` permutation (fixed-stride entries are rows)."""
         from ..kernels import ops  # lazy: keep numpy-only readers jax-free
-        import jax.numpy as jnp
 
         zipped = np.ascontiguousarray(data[: n_unique * stride]).reshape(
             n_unique, stride)
-        gathered = np.asarray(ops.fullzip_gather(
-            jnp.asarray(zipped), jnp.asarray(inv.astype(np.int32))))
+        gathered = ops.fullzip_gather(zipped, inv.astype(np.int32))
         rep, defs, vals = self._decode_fixed(gathered.reshape(-1))
         return leaf_slice(self.proto, rep, defs, vals, len(inv))
 

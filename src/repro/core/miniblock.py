@@ -480,6 +480,7 @@ class MiniBlockReader(ColumnReader):
                                 n_chunks=len(chunk_ids))
             return None
         from ..kernels import ops  # lazy: keep numpy-only readers jax-free
+        from ..kernels.miniblock_decode import MIN_TILE
 
         lt = self.proto.leaf_type
         fsl = isinstance(lt, T.FixedSizeList)
@@ -504,8 +505,13 @@ class MiniBlockReader(ColumnReader):
             return None
         sel = [i for i, p in enumerate(kp) if p is not None]
         parsed = {i: _parse_chunk(raws[i]) for i in sel}
-        tile = -(-max(metas[i]["n_entries"] for i in sel) // 128) * 128
-        params = np.zeros((len(sel), 3), dtype=np.int32)
+        # power-of-two tiles of >= 1024 entries: whole (8, 128) vregs, and a
+        # handful of kernel shapes across batches
+        tile = max(MIN_TILE, ops.pow2(max(metas[i]["n_entries"] for i in sel)))
+        # chunk count and stream widths round up to powers of two (padding
+        # chunks decode to nothing), so batches share compiled shapes
+        n_pad = ops.pow2(len(sel))
+        params = np.zeros((n_pad, 3), dtype=np.int32)
         streams = []  # (rep_words, def_words, val_words) ragged rows
         for j, i in enumerate(sel):
             cm, bufs = metas[i], parsed[i]
@@ -518,9 +524,9 @@ class MiniBlockReader(ColumnReader):
 
         def stack(rows, active):
             if not active:
-                return np.zeros((len(rows), 1), dtype=np.uint32)
-            width = max(len(r) for r in rows)
-            out = np.zeros((len(rows), width), dtype=np.uint32)
+                return np.zeros((n_pad, 1), dtype=np.uint32)
+            width = ops.pow2(max(len(r) for r in rows))
+            out = np.zeros((n_pad, width), dtype=np.uint32)
             for j, r in enumerate(rows):
                 out[j, : len(r)] = r
             return out
@@ -530,26 +536,23 @@ class MiniBlockReader(ColumnReader):
             stack([s[1] for s in streams], def_bits),
             stack([s[2] for s in streams], True),
             params, rep_bits=rep_bits, def_bits=def_bits, vpe=vpe,
-            tile_entries=tile, fill=0))
+            tile_entries=tile))
 
         out: List[tuple] = [None] * len(chunk_ids)
         for j, i in enumerate(sel):
             k = metas[i]["n_entries"]
             rep = rep_np[j, :k].astype(np.uint8) if rep_bits else None
             defs = def_np[j, :k].astype(np.uint8) if def_bits else None
-            valid = (defs == 0) if defs is not None else np.ones(k, bool)
-            n_valid = int(valid.sum())
-            dense = vals_np[j, : k * vpe]
+            n_valid = int((defs == 0).sum()) if defs is not None else k
+            dense = vals_np[j, : n_valid * vpe].astype(dt)
             if fsl:
                 vals = A.FixedSizeListArray(
                     lt.with_nullable(False), np.ones(n_valid, bool),
-                    dense.reshape(k, vpe)[valid].astype(dt),
+                    dense.reshape(n_valid, vpe),
                 )
             else:
                 vals = A.PrimitiveArray(
-                    lt.with_nullable(False), np.ones(n_valid, bool),
-                    dense[:k][valid].astype(dt),
-                )
+                    lt.with_nullable(False), np.ones(n_valid, bool), dense)
             out[i] = (rep, defs, vals)
         for i, p in enumerate(kp):
             if p is None:

@@ -76,11 +76,12 @@ class IvfIndex:
     """
 
     def __init__(self, writer: DatasetWriter, column: str,
-                 n_partitions: int, dim: int):
+                 n_partitions: int, dim: int, decode: Optional[str] = None):
         self.writer = writer          # attached: shares the data IO path
         self.column = column
         self.n_partitions = int(n_partitions)
         self.dim = int(dim)
+        self.decode = decode          # index decode route (None: writer's)
 
     @classmethod
     def build(cls, data: DatasetWriter, column: str = "embedding",
@@ -118,7 +119,7 @@ class IvfIndex:
     def reader(self, version: Optional[int] = None):
         """Index fragments at a committed index-manifest version (time
         travel over the index, independent of data versions)."""
-        return self.writer.reader(version)
+        return self.writer.reader(version, decode=self.decode)
 
     def centroids(self, version: Optional[int] = None) -> np.ndarray:
         """(P, dim) float32 — one batched take of every centroid row (warm

@@ -87,8 +87,9 @@ class DatasetWriter:
         self._pending: List[Fragment] = []    # appended since last commit
         self.versions: List[Manifest] = []    # committed manifests, v1..vN
         self._next_id = 0
-        self._frag_readers: Dict[int, FileReader] = {}
-        self._version_readers: Dict[int, DatasetReader] = {}
+        # reader caches, keyed by (fragment id | version, decode route)
+        self._frag_readers: Dict[tuple, FileReader] = {}
+        self._version_readers: Dict[tuple, DatasetReader] = {}
         if files:
             for fb in files:
                 self._append_file(bytes(fb))
@@ -221,32 +222,36 @@ class DatasetWriter:
         return self.scheduler.flush_barrier()
 
     # -- reading -------------------------------------------------------------
-    def _reader_for(self, frag: Fragment) -> FileReader:
-        fr = self._frag_readers.get(frag.id)
+    def _reader_for(self, frag: Fragment,
+                    decode: Optional[str] = None) -> FileReader:
+        decode = decode or self._decode
+        fr = self._frag_readers.get((frag.id, decode))
         if fr is None:
             fr = FileReader(DiskView(self.disk, frag.base, frag.nbytes),
                             scheduler=self.scheduler, base=frag.base,
-                            decode=self._decode, dict_cached=self._dict_cached)
-            self._frag_readers[frag.id] = fr
+                            decode=decode, dict_cached=self._dict_cached)
+            self._frag_readers[(frag.id, decode)] = fr
         return fr
 
-    def reader(self, version: Optional[int] = None) -> DatasetReader:
+    def reader(self, version: Optional[int] = None,
+               decode: Optional[str] = None) -> DatasetReader:
         """A :class:`DatasetReader` over a committed manifest version (1-based;
         default latest), sharing this writer's store/scheduler — reads it
         serves are priced on, and warm, the same NVMe budget the ingest path
-        is filling."""
+        is filling.  ``decode`` overrides the writer's decode route."""
         if not self.versions:
             raise ValueError("nothing committed yet — append() first")
         v = len(self.versions) if version is None else int(version)
         if not 1 <= v <= len(self.versions):
             raise ValueError(f"version {v} out of range 1..{len(self.versions)}")
-        ds = self._version_readers.get(v)
+        decode = decode or self._decode
+        ds = self._version_readers.get((v, decode))
         if ds is None:
             m = self.versions[v - 1]
             ds = DatasetReader.from_manifest(
                 m, self.disk, self.scheduler,
-                readers=[self._reader_for(f) for f in m.fragments])
-            self._version_readers[v] = ds
+                readers=[self._reader_for(f, decode) for f in m.fragments])
+            self._version_readers[(v, decode)] = ds
         return ds
 
     def take(self, name: str, rows) -> A.Array:

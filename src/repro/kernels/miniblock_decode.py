@@ -1,12 +1,11 @@
 """Pallas TPU kernel: mini-block chunk decode.
 
 One grid step decodes one mini-block chunk (§4.2): unpack the bit-packed
-repetition / definition level streams, unpack the (frame-of-reference)
-bit-packed or byte-packed values, and scatter them densely (fill at nulls).
-Per-chunk parameters (entry count, value bit width, FoR reference) arrive via
-scalar prefetch; chunk payloads are padded to a common word count so the
-BlockSpec tiling is static — the mini-block format's power-of-two/8-byte-
-aligned chunk rules (§4.2.1) exist precisely to make this tiling possible.
+repetition / definition level streams and the (frame-of-reference)
+bit-packed or byte-packed values.  Per-chunk parameters (entry count, value
+bit width, FoR reference) arrive via scalar prefetch; every stream arrives
+in the row-window layout of :func:`repro.kernels.bitunpack.row_windows`, so
+the kernel body is in-row lane gathers and shifts only.
 
 Coverage (static per call, constant per column):
 
@@ -19,10 +18,14 @@ Coverage (static per call, constant per column):
   (``bitpack``), or byte-aligned FoR (``bytepack``, width*8 bits) with the
   per-chunk reference added back.
 
-VMEM budget: a chunk is <=32 KiB by construction (12-bit word count), plus
-the ``(tile_entries * vpe,)`` int32 output tile — the reader caps
-``tile_entries * vpe`` so this stays comfortably inside the ~16 MiB VMEM of
-a TPU core even with double buffering.
+Values come back in stream order (the i-th non-null value at slot i), which
+is what a reader assembles arrays from; placing them at entry positions is
+left to the caller.
+
+VMEM budget: a chunk holds <= 4096 entries, so a grid step keeps the level
+tiles at <= 32 KiB each plus the ``(tile_entries * vpe,)`` value tile — the
+reader caps ``tile_entries * vpe`` so this stays far inside a TPU core's
+VMEM even with double buffering.
 """
 
 from __future__ import annotations
@@ -34,79 +37,46 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["miniblock_decode_pallas", "MAX_ENTRIES"]
+from .bitunpack import LANES, row_windows, unpack_rows
+
+__all__ = ["miniblock_decode_pallas", "MAX_ENTRIES", "MIN_TILE"]
 
 MAX_ENTRIES = 4096  # the format's per-chunk value ceiling (sec 4.2.1)
+MIN_TILE = 8 * LANES  # tiles are whole (8, 128) int32 vregs
 
 
-def _iota(n: int) -> jax.Array:
-    """1-D uint32 iota via a 2-D broadcasted iota (TPU needs >=2-D)."""
-    return (
-        jax.lax.broadcasted_iota(jnp.uint32, (n // 128, 128), 0) * 128
-        + jax.lax.broadcasted_iota(jnp.uint32, (n // 128, 128), 1)
-    ).reshape(-1)
-
-
-def _extract(words, bitpos, bits, mask):
-    """Little-endian ``bits``-wide field at ``bitpos`` of a uint32 stream."""
-    w = (bitpos // 32).astype(jnp.int32)
-    sh = bitpos % 32
-    w0 = jnp.take(words, w, axis=0)
-    w1 = jnp.take(words, jnp.minimum(w + 1, words.shape[0] - 1), axis=0)
-    hi_shift = (jnp.uint32(32) - sh) & jnp.uint32(31)
-    hi = jnp.where(sh > 0, w1 << hi_shift, jnp.uint32(0))
-    return ((w0 >> sh) | hi) & mask
-
-
-def _kernel(params_ref, rep_ref, def_ref, val_ref,
-            out_rep_ref, out_def_ref, out_val_ref,
-            *, rep_bits: int, def_bits: int, vpe: int, tile: int, fill: int):
+def _kernel(params_ref, *refs, rep_bits: int, def_bits: int, vpe: int):
+    n_in = 1 + bool(rep_bits) + bool(def_bits)
+    ins, outs = refs[:n_in], refs[n_in:]
     c = pl.program_id(0)
     n = params_ref[c, 0]
     bits = params_ref[c, 1].astype(jnp.uint32)
     ref = params_ref[c, 2]
 
-    j = _iota(tile)
-    in_range = j < n.astype(jnp.uint32)
-    if rep_bits:
-        rep = _extract(rep_ref[0, :], j * rep_bits,
-                       jnp.uint32(rep_bits), jnp.uint32((1 << rep_bits) - 1))
-        out_rep_ref[...] = jnp.where(in_range, rep.astype(jnp.int32), 0).reshape(
-            tile // 128, 128)
-    else:
-        out_rep_ref[...] = jnp.zeros((tile // 128, 128), jnp.int32)
-    if def_bits:
-        d = _extract(def_ref[0, :], j * def_bits,
-                     jnp.uint32(def_bits), jnp.uint32((1 << def_bits) - 1))
-        valid = (d == 0) & in_range
-        out_def_ref[...] = jnp.where(in_range, d.astype(jnp.int32), 0).reshape(
-            tile // 128, 128)
-    else:
-        valid = in_range
-        out_def_ref[...] = jnp.zeros((tile // 128, 128), jnp.int32)
-    # value slot of each entry: cumsum over the validity mask
-    vidx = (jnp.cumsum(valid.astype(jnp.int32)) - 1).astype(jnp.uint32)
-
-    # each valid entry owns vpe consecutive values in the dense stream
-    k = _iota(tile * vpe)
-    e = (k // jnp.uint32(vpe)).astype(jnp.int32)
-    valid_k = jnp.take(valid, e, axis=0)
-    slot = jnp.take(vidx, e, axis=0) * jnp.uint32(vpe) + k % jnp.uint32(vpe)
-    bitpos = jnp.where(valid_k, slot, 0) * bits
-    mask = jnp.where(bits >= 32, jnp.uint32(0xFFFFFFFF),
-                     (jnp.uint32(1) << bits) - jnp.uint32(1))
-    vals = _extract(val_ref[0, :], bitpos, bits, mask)
-    out = jnp.where(valid_k, vals.astype(jnp.int32) + ref, fill)
-    out_val_ref[...] = out.reshape(tile * vpe // 128, 128)
+    shape = ins[-1].shape[1:]
+    rows = shape[0] // vpe
+    entry = (jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 0) * LANES
+             + jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1))
+    in_range = entry < n
+    lv = None
+    for i, b in enumerate(x for x in (rep_bits, def_bits) if x):
+        lv = jnp.where(in_range, unpack_rows(ins[i][0], b).astype(jnp.int32), 0)
+        outs[i][0] = lv
+    valid = in_range & (lv == 0) if def_bits else in_range
+    n_vals = jnp.sum(valid.astype(jnp.int32), axis=(0, 1), keepdims=True) * vpe
+    slot = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * LANES
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    vals = unpack_rows(ins[-1][0], bits).astype(jnp.int32) + ref
+    outs[-1][0] = jnp.where(slot < n_vals, vals, 0)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("rep_bits", "def_bits", "vpe", "tile_entries", "fill",
+    static_argnames=("rep_bits", "def_bits", "vpe", "tile_entries",
                      "interpret"))
 def miniblock_decode_pallas(
-    rep_words: jax.Array,  # (C, RW) uint32 (dummy (C, 1) when rep_bits == 0)
-    def_words: jax.Array,  # (C, DW) uint32 (dummy (C, 1) when def_bits == 0)
+    rep_words: jax.Array,  # (C, RW) uint32 (ignored when rep_bits == 0)
+    def_words: jax.Array,  # (C, DW) uint32 (ignored when def_bits == 0)
     val_words: jax.Array,  # (C, VW) uint32
     params: jax.Array,  # (C, 3) int32: [n_entries, vbits, ref]
     *,
@@ -114,44 +84,36 @@ def miniblock_decode_pallas(
     def_bits: int,
     vpe: int = 1,
     tile_entries: int = MAX_ENTRIES,
-    fill: int = 0,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Decode C chunks -> (rep, defs, vals) int32 tiles.
 
     ``rep``/``defs`` are ``(C, tile_entries)`` level streams (zero where the
-    stream is absent or past ``n_entries``); ``vals`` is the dense
-    ``(C, tile_entries * vpe)`` value tile with ``fill`` at nulls.
+    stream is absent or past ``n_entries``); ``vals`` is ``(C, tile_entries *
+    vpe)``: the chunk's values in stream order, zero past the last one.
     """
-    assert tile_entries % 128 == 0 and (tile_entries * vpe) % 128 == 0
+    assert tile_entries % MIN_TILE == 0, tile_entries
     C = params.shape[0]
-    RW, DW, VW = rep_words.shape[1], def_words.shape[1], val_words.shape[1]
-    R = tile_entries // 128
-    RV = tile_entries * vpe // 128
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(C,),
-        in_specs=[
-            pl.BlockSpec((1, RW), lambda c, p: (c, 0)),
-            pl.BlockSpec((1, DW), lambda c, p: (c, 0)),
-            pl.BlockSpec((1, VW), lambda c, p: (c, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((R, 128), lambda c, p: (c, 0)),
-            pl.BlockSpec((R, 128), lambda c, p: (c, 0)),
-            pl.BlockSpec((RV, 128), lambda c, p: (c, 0)),
-        ],
-    )
-    rep, defs, vals = pl.pallas_call(
+    R = tile_entries // LANES
+    streams = [row_windows(w, b, R) for w, b in
+               ((rep_words, rep_bits), (def_words, def_bits)) if b]
+    streams.append(row_windows(val_words, params[:, 1], R * vpe))
+    n_levels = len(streams) - 1
+    spec = lambda r: pl.BlockSpec((1, r, LANES), lambda c, p: (c, 0, 0))  # noqa: E731
+    outs = pl.pallas_call(
         functools.partial(_kernel, rep_bits=rep_bits, def_bits=def_bits,
-                          vpe=vpe, tile=tile_entries, fill=fill),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((C * R, 128), jnp.int32),
-            jax.ShapeDtypeStruct((C * R, 128), jnp.int32),
-            jax.ShapeDtypeStruct((C * RV, 128), jnp.int32),
-        ],
+                          vpe=vpe),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(C,),
+            in_specs=[spec(s.shape[1]) for s in streams],
+            out_specs=[spec(s.shape[1]) for s in streams],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(s.shape, jnp.int32) for s in streams],
         interpret=interpret,
-    )(params, rep_words, def_words, val_words)
-    return (rep.reshape(C, tile_entries), defs.reshape(C, tile_entries),
-            vals.reshape(C, tile_entries * vpe))
+    )(params, *streams)
+    levels = iter(o.reshape(C, tile_entries) for o in outs[:n_levels])
+    zeros = jnp.zeros((C, tile_entries), jnp.int32)
+    rep = next(levels) if rep_bits else zeros
+    defs = next(levels) if def_bits else zeros
+    return rep, defs, outs[-1].reshape(C, tile_entries * vpe)

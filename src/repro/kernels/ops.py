@@ -1,9 +1,8 @@
-"""Public jit'd kernel entry points.
+"""Public kernel entry points.
 
-Each op dispatches to the Pallas TPU kernel (interpret=True on CPU so the
-kernel *body* is what executes) or to the pure-jnp oracle in ``ref.py``.
-On a real TPU backend ``interpret`` flips to False and the same code lowers
-to Mosaic.
+Each op dispatches to the Pallas TPU kernel or to the pure-jnp oracle in
+``ref.py``.  On a TPU the kernel lowers to Mosaic; on any other backend it
+runs in interpret mode, so the CPU test suite executes the kernel *body*.
 """
 
 from __future__ import annotations
@@ -14,8 +13,8 @@ import numpy as np
 
 from . import ref
 from .bitunpack import VALS_PER_BLOCK, bitunpack_pallas
-from .fullzip_gather import fullzip_gather_pallas
-from .ivf_topk import K_PAD, QUERY_TILE, ivf_topk_pallas
+from .fullzip_gather import ROW_WORDS, fullzip_gather_pallas
+from .ivf_topk import K_PAD, QUERY_TILE, cand_tile, ivf_topk_pallas
 from .miniblock_decode import MAX_ENTRIES, miniblock_decode_pallas
 from .ref import IVF_ID_SENTINEL
 
@@ -25,6 +24,7 @@ __all__ = [
     "fullzip_gather",
     "ivf_topk",
     "pack_words",
+    "pow2",
     "on_tpu",
     "IVF_ID_SENTINEL",
 ]
@@ -67,49 +67,74 @@ def miniblock_decode(
     def_bits: int,
     vpe: int = 1,
     tile_entries: int = MAX_ENTRIES,
-    fill: int = 0,
     use_pallas: bool = True,
 ):
     """Decode C mini-block chunks -> ``(rep, defs, vals)`` int32 tiles.
 
-    ``rep``/``defs`` are ``(C, tile_entries)``; ``vals`` is the dense
-    ``(C, tile_entries * vpe)`` tile (``vpe`` values per valid entry —
-    fixed-size-list chunks set it to the list size).  Entries past a chunk's
-    ``n_entries`` and null value slots read as 0 / ``fill``.
+    ``rep``/``defs`` are ``(C, tile_entries)``, zero past a chunk's
+    ``n_entries``; ``vals`` is ``(C, tile_entries * vpe)``: the chunk's
+    values in stream order (``vpe`` values per valid entry — fixed-size-list
+    chunks set it to the list size), zero past the last one.
+    ``tile_entries`` is a multiple of 1024.
     """
     if not use_pallas:
         return ref.miniblock_decode_ref(
             rep_words, def_words, val_words,
             params[:, 0], params[:, 1], params[:, 2],
-            tile_entries, rep_bits, def_bits, vpe, fill,
+            tile_entries, rep_bits, def_bits, vpe,
         )
     return miniblock_decode_pallas(
         rep_words, def_words, val_words, params,
         rep_bits=rep_bits, def_bits=def_bits, vpe=vpe,
-        tile_entries=tile_entries, fill=fill,
-        interpret=not on_tpu(),
+        tile_entries=tile_entries, interpret=not on_tpu(),
     )
 
 
-def fullzip_gather(zipped: jax.Array, rows: jax.Array, *, use_pallas: bool = True) -> jax.Array:
-    """Gather zipped fixed-stride rows (the §4.1 take path)."""
+def fullzip_gather(zipped, rows, *, use_pallas: bool = True):
+    """Gather zipped fixed-stride rows (the §4.1 take path).
+
+    ``zipped`` is (n_rows, ...) of any dtype (the take path's rows are
+    uint8 [control word | value bytes]), ``rows`` (n_take,) int; returns
+    ``zipped[rows]``.  The Pallas route moves each row's bytes as padded
+    uint32 words, which are built and taken apart on the host.
+    """
     if not use_pallas:
         return ref.fullzip_gather_ref(zipped, rows)
-    return fullzip_gather_pallas(zipped, rows, interpret=not on_tpu())
+    z = np.ascontiguousarray(np.asarray(zipped))
+    row = z.reshape(len(z), -1).view(np.uint8)
+    nbytes = row.shape[1]
+    # row counts round up to powers of two so takes share compiled shapes
+    padded = np.zeros((pow2(len(z)),
+                       -(-nbytes // (4 * ROW_WORDS)) * 4 * ROW_WORDS), np.uint8)
+    padded[: len(z), :nbytes] = row
+    ids = np.zeros(pow2(len(rows)), np.int32)
+    ids[: len(rows)] = np.asarray(rows)
+    out = fullzip_gather_pallas(jnp.asarray(padded.view(np.uint32)),
+                                jnp.asarray(ids), interpret=not on_tpu())
+    got = np.ascontiguousarray(
+        np.asarray(out)[: len(rows)].view(np.uint8)[:, :nbytes])
+    return got.view(z.dtype).reshape((-1,) + z.shape[1:])
+
+
+def pow2(n: int) -> int:
+    """Smallest power of two >= n: padded sizes that share compiled shapes."""
+    return 1 << max(0, int(n) - 1).bit_length()
 
 
 def _ivf_pad(queries, cands, ids, mask):
     """Pad (queries, cands, ids, mask) to the kernel's static tiling:
-    query rows to a multiple of 8, candidates to a multiple of 128, dims
-    to a multiple of 128.  Zero dim-padding is L2-exact; padded candidate
-    columns are masked out and carry the id sentinel."""
+    query rows to a multiple of 8, dims to a multiple of 128, candidates to
+    a multiple of the kernel's candidate tile.  Zero dim-padding is
+    L2-exact; padded candidate columns are masked out and carry the id
+    sentinel."""
     q2 = np.atleast_2d(np.asarray(queries))
     c2 = np.atleast_2d(np.asarray(cands))
     qn, d = q2.shape
     n = c2.shape[0]
     qp = -(-max(qn, 1) // QUERY_TILE) * QUERY_TILE
-    np_ = -(-max(n, 1) // 128) * 128
     dp = -(-max(d, 1) // 128) * 128
+    tn = cand_tile(n, dp)
+    np_ = tn * pow2(-(-max(n, 1) // tn))  # a power of two of tiles
     qpad = np.zeros((qp, dp), q2.dtype)
     qpad[:qn, :d] = q2
     cpad = np.zeros((np_, dp), c2.dtype)
@@ -135,7 +160,10 @@ def ivf_topk(queries, cands, ids, k: int, mask=None, *,
     (1 = candidate in one of this query's probed partitions).  Returns
     ``(dists, winners)`` of shape (Q, k) — ties break toward the lowest
     row id, entries past a query's eligible count hold
-    ``(inf, IVF_ID_SENTINEL)``.
+    ``(inf, IVF_ID_SENTINEL)``.  Both routes agree with a float64 reference
+    within :func:`repro.kernels.ref.topk_tolerance` (see
+    :func:`repro.kernels.ref.topk_mismatches`), not bit for bit: they sum
+    in different orders.
 
     Dispatches to the Pallas kernel when eligible (float32 vectors, ids
     within 31 bits, k <= 128, at least one candidate); otherwise falls

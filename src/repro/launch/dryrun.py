@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs import ARCHS, SHAPES, get_config
-from ..compat import set_mesh
 from ..dist.sharding import ShardingPolicy
 from ..models.registry import (
     build_model,
@@ -150,7 +149,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, hlo_dir=None, fsdp=Non
     mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = 512 if multi_pod else 256
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn, args, in_sh, out_sh, donate = build_cell(arch, shape_name, mesh, fsdp=fsdp)
             jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                              donate_argnums=donate)

@@ -23,7 +23,6 @@ import numpy as np
 
 from ..configs import get_config, reduced_config
 from ..data.loader import TokenLoader, write_token_file
-from ..compat import set_mesh
 from ..dist.checkpoint import Checkpointer
 from ..dist.fault import DataCursor, HeartbeatMonitor, RestartPolicy, run_with_restarts
 from ..dist.sharding import ShardingPolicy
@@ -43,7 +42,7 @@ def train(arch: str, *, reduced: bool = True, steps: int = 50, batch: int = 8,
     model = build_model(cfg, mesh=mesh, batch_axes=policy.batch_axes(),
                         data_size=mesh.shape["data"], use_sharded_moe=False)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params, specs = model.init(jax.random.PRNGKey(0))
         p_sh = policy.param_shardings(specs)
         params = jax.tree.map(lambda x, s: jax.device_put(x, s), params, p_sh)

@@ -96,11 +96,9 @@ def moe_apply_sharded(params, cfg, x, mesh_axes=("data", "model")):
     all_to_all over ``data`` (dispatch / return), psum over ``model``
     (row-parallel wo).
     """
-    from ..compat import axis_size
-
     m = cfg.moe
     data_axis, model_axis = mesh_axes
-    data_size = axis_size(data_axis)
+    data_size = jax.lax.axis_size(data_axis)
     slots = expert_slots(m.n_experts, data_size)
     reps = slots // m.n_experts
     B, S, d = x.shape
@@ -146,7 +144,7 @@ def moe_apply_sharded(params, cfg, x, mesh_axes=("data", "model")):
     # slice through the return all-to-all and combine, then all-gather once.
     # Collective payload per layer: RS(1/16) + a2a(1/16) + AG(1) ≈ 0.3x the
     # [AR(1) + a2a(1)] baseline.
-    model_size = axis_size(model_axis)
+    model_size = jax.lax.axis_size(model_axis)
     ds = d // model_size
     o = jax.lax.psum_scatter(o.astype(x.dtype), model_axis,
                              scatter_dimension=2, tiled=True)

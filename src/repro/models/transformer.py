@@ -217,13 +217,12 @@ class LMModel:
                 "wg": {"w": P(None, "model")},
                 "wo": {"w": P("model", None)},
             }
-        from ..compat import shard_map
-
-        return shard_map(
+        return jax.shard_map(
             lambda pp, xx: moe_apply_sharded(pp, self.cfg, xx),
             mesh=self.mesh,
             in_specs=(pspec, bspec),
             out_specs=(bspec, {"aux": P(), "dropped": P()}),
+            check_vma=False,
         )(p, x)
 
     def _dense_layer_apply(self, p, x, positions, mode, cache):

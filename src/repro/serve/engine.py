@@ -153,9 +153,10 @@ class Retriever:
         half.  Accepts one query ``(D,)`` or a batch ``(Q, D)``;
         multi-query batches score one shared candidate matrix under a
         per-query partition mask, so each query still sees exactly its own
-        ``nprobe`` probes.  Deterministic end to end: k-means is seeded,
-        ties break toward the lowest row id, and the numpy/Pallas kernel
-        routes are bit-identical (``decode`` knob).
+        ``nprobe`` probes.  Deterministic end to end: k-means is seeded
+        and ties break toward the lowest row id.  The numpy and Pallas
+        distance routes (``decode`` knob) each agree with float64 top-k up
+        to ties within :func:`repro.kernels.ref.topk_tolerance`.
         """
         if self.index is None:
             raise ValueError(

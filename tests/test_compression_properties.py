@@ -1,16 +1,12 @@
-"""Property-based codec tests (optional: require ``hypothesis``).
+"""Property-based codec tests.
 
-The whole module is skipped on a bare interpreter; the example-based
-equivalents stay in ``test_compression.py``."""
+The example-based equivalents stay in ``test_compression.py``."""
 
 import numpy as np
-import pytest
 
-pytest.importorskip("hypothesis")
+from hypothesis import given, settings, strategies as st
 
-from hypothesis import given, settings, strategies as st  # noqa: E402
-
-from repro.core.compression import get_bytes_codec, get_fixed_codec  # noqa: E402
+from repro.core.compression import get_bytes_codec, get_fixed_codec
 
 rng = np.random.default_rng(0)
 

@@ -1,21 +1,18 @@
-"""Property-based decode-parity tests (optional: require ``hypothesis``).
+"""Property-based decode-parity tests.
 
 The row-parallel full-zip decode (frontier walk over row spans, pointer-
 doubling entry discovery for scans) must be bit-identical to the retained
 sequential per-value walk (``FullZipReader._decode_entries_walk``) over
-arbitrary rep/def/null/length shapes.  The whole module is skipped on a bare
-interpreter; example-based equivalents live in ``test_take_pipeline.py``.
+arbitrary rep/def/null/length shapes.
+Example-based equivalents live in ``test_take_pipeline.py``.
 """
 
 import numpy as np
-import pytest
 
-pytest.importorskip("hypothesis")
+from hypothesis import given, settings, strategies as st
 
-from hypothesis import given, settings, strategies as st  # noqa: E402
-
-from repro.core import arrays as A, types as T  # noqa: E402
-from repro.core.file import FileReader, WriteOptions, write_table  # noqa: E402
+from repro.core import arrays as A, types as T
+from repro.core.file import FileReader, WriteOptions, write_table
 
 
 def _leaf_reader(arr: A.Array, bytes_codec=None):

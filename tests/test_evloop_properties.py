@@ -1,4 +1,4 @@
-"""Property-based event-loop contracts (optional: require ``hypothesis``).
+"""Property-based event-loop contracts.
 
 The lone-batch degeneration property, stated over arbitrary drain shapes:
 for ANY drain record (any tier subset, any phase structure, any op/byte
@@ -13,13 +13,11 @@ import math
 
 import pytest
 
-pytest.importorskip("hypothesis")
+from hypothesis import given, settings, strategies as st
 
-from hypothesis import given, settings, strategies as st  # noqa: E402
-
-from repro.core.io_sim import DRAM, NVME, S3  # noqa: E402
-from repro.store import EventLoop, build_job  # noqa: E402
-from repro.store.stats import DrainRecord, TierStats  # noqa: E402
+from repro.core.io_sim import DRAM, NVME, S3
+from repro.store import EventLoop, build_job
+from repro.store.stats import DrainRecord, TierStats
 
 DEVICES = [DRAM, NVME, S3]
 

@@ -1,4 +1,4 @@
-"""Property-based failure/recovery contracts (optional: require ``hypothesis``).
+"""Property-based failure/recovery contracts.
 
 Stated over arbitrary drain shapes and fault schedules:
 
@@ -21,16 +21,12 @@ Stated over arbitrary drain shapes and fault schedules:
     completed + failed + shed == submitted, each label exactly once.
 """
 
-import pytest
+from hypothesis import given, settings, strategies as st
 
-pytest.importorskip("hypothesis")
-
-from hypothesis import given, settings, strategies as st  # noqa: E402
-
-from repro.core.io_sim import NVME, S3, Blackout, TransientErrors  # noqa: E402
-from repro.obs.slo import Shedder, SLObjective, SLOMonitor  # noqa: E402
-from repro.store import EventLoop, RetryPolicy, build_job  # noqa: E402
-from repro.store.stats import DrainRecord  # noqa: E402
+from repro.core.io_sim import NVME, S3, Blackout, TransientErrors
+from repro.obs.slo import Shedder, SLObjective, SLOMonitor
+from repro.store import EventLoop, RetryPolicy, build_job
+from repro.store.stats import DrainRecord
 
 DEVICES = [NVME, S3]
 

@@ -1,24 +1,21 @@
-"""Property-based crash-consistency tests (optional: require ``hypothesis``).
+"""Property-based crash-consistency tests.
 
 The flush-then-commit fence's contract, stated as a property: for ANY
 interleaving of appends (committed or staged), commits, interrupted flushes
 (a crash after any prefix of the flush's dispatched extents), and crashes,
 every manifest version that was ever committed remains readable with exactly
-the rows it committed.  The whole module is skipped on a bare interpreter;
-example-based equivalents live in ``test_ingest.py``.
+the rows it committed.
+Example-based equivalents live in ``test_ingest.py``.
 """
 
 import numpy as np
-import pytest
 
-pytest.importorskip("hypothesis")
+from hypothesis import given, settings, strategies as st
 
-from hypothesis import given, settings, strategies as st  # noqa: E402
-
-from repro.core import arrays as A  # noqa: E402
-from repro.core.file import WriteOptions  # noqa: E402
-from repro.dataset import DatasetWriter  # noqa: E402
-from repro.store import FlushPolicy, SimulatedCrash, TieredStore  # noqa: E402
+from repro.core import arrays as A
+from repro.core.file import WriteOptions
+from repro.dataset import DatasetWriter
+from repro.store import FlushPolicy, SimulatedCrash, TieredStore
 
 # one scripted ingest step: (op, size-ish argument)
 #   append  — stage a fragment of `arg` rows (committed if arg is odd)

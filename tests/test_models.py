@@ -164,12 +164,12 @@ x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model), jnp.float32)
 ref, _ = moe_apply_reference(params, cfg, x)
 pspec = {"router": {"w": P(None, None)}, "wi": P("data", None, "model"),
          "wg": P("data", None, "model"), "wo": P("data", "model", None)}
-from repro.compat import set_mesh, shard_map
-with set_mesh(mesh):
-    out, aux = jax.jit(shard_map(
+with jax.set_mesh(mesh):
+    out, aux = jax.jit(jax.shard_map(
         lambda pp, xx: moe_apply_sharded(pp, cfg, xx),
         mesh=mesh, in_specs=(pspec, P(("data",), None, None)),
-        out_specs=(P(("data",), None, None), {"aux": P(), "dropped": P()})))(params, x)
+        out_specs=(P(("data",), None, None), {"aux": P(), "dropped": P()}),
+        check_vma=False))(params, x)
 err = float(jnp.abs(ref - out).max() / (jnp.abs(ref).max() + 1e-9))
 print("rel err", err, "dropped", float(aux["dropped"]))
 assert err < 2e-2, err

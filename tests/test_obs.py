@@ -364,8 +364,8 @@ def test_run_meta_and_nan_refusal(bench_run, tmp_path):
     out = tmp_path / "BENCH_t.json"
     bench_run._dump_json(str(out), {"v": 1})
     doc = json.loads(out.read_text())
-    assert {"git_sha", "store", "smoke", "timestamp", "traced"} <= \
-        set(doc["meta"]["run"])
+    assert {"git_sha", "store", "smoke", "timestamp", "traced", "platform",
+            "device_kind", "device_count"} <= set(doc["meta"]["run"])
     with pytest.raises(ValueError):
         bench_run._dump_json(str(out), {"v": float("nan")})
 

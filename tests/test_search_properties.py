@@ -1,4 +1,4 @@
-"""Property-based IVF search tests (optional: require ``hypothesis``).
+"""Property-based IVF search tests.
 
 The search path's contracts, stated as properties over random datasets,
 partition counts and query batches:
@@ -16,16 +16,13 @@ partition counts and query batches:
 """
 
 import numpy as np
-import pytest
 
-pytest.importorskip("hypothesis")
+from hypothesis import given, settings, strategies as st
 
-from hypothesis import given, settings, strategies as st  # noqa: E402
-
-from repro.core import arrays as A  # noqa: E402
-from repro.core.file import WriteOptions  # noqa: E402
-from repro.dataset import DatasetWriter, IvfIndex, write_fragments  # noqa: E402
-from repro.serve.engine import Retriever  # noqa: E402
+from repro.core import arrays as A
+from repro.core.file import WriteOptions
+from repro.dataset import DatasetWriter, IvfIndex, write_fragments
+from repro.serve.engine import Retriever
 
 
 def _build(n_rows, dim, n_fragments, n_partitions, seed, decode=None):

@@ -1,7 +1,7 @@
 """Shredding (Dremel rep/def) correctness: paper examples + case table.
 
 The hypothesis roundtrip properties over arbitrary nested types live in
-``test_shred_properties.py`` so this module runs on a bare interpreter."""
+``test_shred_properties.py``."""
 
 import numpy as np
 import pytest

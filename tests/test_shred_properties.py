@@ -1,16 +1,13 @@
-"""Property-based shredding tests over arbitrary nested types (optional:
-require ``hypothesis``).  Example-based cases stay in ``test_shred.py``."""
+"""Property-based shredding tests over arbitrary nested types.
+Example-based cases stay in ``test_shred.py``."""
 
 import numpy as np
-import pytest
 
-pytest.importorskip("hypothesis")
+from hypothesis import given, settings, strategies as st
 
-from hypothesis import given, settings, strategies as st  # noqa: E402
-
-from repro.core import arrays as A  # noqa: E402
-from repro.core import types as T  # noqa: E402
-from repro.core.shred import shred, unshred  # noqa: E402
+from repro.core import arrays as A
+from repro.core import types as T
+from repro.core.shred import shred, unshred
 
 
 def rt(pyvals, typ):

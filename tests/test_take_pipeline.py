@@ -182,7 +182,6 @@ def _bit_identical(a: A.Array, b: A.Array):
 def test_miniblock_pallas_parity(kind):
     """decode='pallas' (interpret mode) is bit-identical to numpy on the
     bit-packed flat integer path, for take and scan."""
-    pytest.importorskip("jax")
     arr = _dataset(kind, 5000)  # several chunks
     fb = write_table({"c": arr}, WriteOptions("lance-miniblock"))
     fr_np = FileReader(fb, decode="numpy")
@@ -200,7 +199,6 @@ def test_miniblock_pallas_parity(kind):
 def test_miniblock_pallas_fallback_codecs():
     """Codecs the kernel doesn't cover (floats/utf8) fall back to numpy and
     still roundtrip under decode='pallas'."""
-    pytest.importorskip("jax")
     for kind in ["utf8", "fixed-size-list"]:
         arr = _dataset(kind, 400)
         fb = write_table({"c": arr}, WriteOptions("lance-miniblock"))
@@ -234,7 +232,6 @@ def test_miniblock_pallas_widened_coverage(name, build, kw):
     """Chunk shapes that used to hit the numpy fallback — multi-bit def
     streams, rep streams, FoR bytepack, fixed-size-list values — now decode
     through the kernel bit-identically, with identical logical IO."""
-    pytest.importorskip("jax")
     arr = build()
     n = len(arr)
     fb = write_table({"c": arr}, WriteOptions("lance-miniblock", **kw))
@@ -252,7 +249,6 @@ def test_miniblock_pallas_widened_coverage(name, build, kw):
 def test_miniblock_widened_chunks_use_kernel():
     """The widened shapes actually route through the kernel (no silent
     fallback): the batched pallas decode path must claim the chunks."""
-    pytest.importorskip("jax")
     for name, build, kw in WIDENED:
         arr = build()
         fb = write_table({"c": arr}, WriteOptions("lance-miniblock", **kw))
@@ -276,7 +272,6 @@ def test_fullzip_pallas_gather_route(kind):
     """decode='pallas' routes the fixed-stride full-zip take through the
     fullzip_gather kernel: bit-identical to the host permutation, with
     identical logical IO (duplicates still served from one read)."""
-    pytest.importorskip("jax")
     arr = _dataset(kind, 700)
     fb = write_table({"c": arr}, WriteOptions("lance-fullzip"))
     fr_np = FileReader(fb, decode="numpy")
@@ -293,7 +288,6 @@ def test_fullzip_pallas_gather_route(kind):
 def test_fullzip_pallas_var_width_unaffected():
     """The gather route only covers fixed strides; variable-width full-zip
     under decode='pallas' still takes the row-parallel host path."""
-    pytest.importorskip("jax")
     arr = _dataset("utf8", 400)
     fb = write_table({"c": arr}, WriteOptions("lance-fullzip"))
     want = A.to_pylist(arr)
@@ -322,7 +316,6 @@ def test_scan_windows_any_chunk_size(encname, opts, kind, io_chunk):
 def test_decode_knob_in_write_options():
     """WriteOptions(decode=...) is recorded in the footer and picked up as
     the reader default; an explicit reader arg overrides it."""
-    pytest.importorskip("jax")
     arr = _dataset("primitive", 300)
     fb = write_table({"c": arr}, WriteOptions("lance-miniblock", decode="pallas"))
     fr = FileReader(fb)

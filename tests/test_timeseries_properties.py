@@ -1,7 +1,6 @@
 """Property tests for the log-bucket histogram and its windowed ring.
 
-Requires ``hypothesis`` (skipped when absent, same policy as the other
-property suites).  The properties are the tentpole contracts stated in
+The properties are the tentpole contracts stated in
 ``repro/obs/timeseries.py``:
 
 * merge is exact, associative, and commutative — merging histograms is
@@ -14,14 +13,10 @@ property suites).  The properties are the tentpole contracts stated in
   out-of-order) virtual timestamps.
 """
 
-import pytest
+from hypothesis import given, settings, strategies as st
 
-hypothesis = pytest.importorskip("hypothesis")
-
-from hypothesis import given, settings, strategies as st  # noqa: E402
-
-from repro.obs.metrics import percentile  # noqa: E402
-from repro.obs.timeseries import LogBucketHistogram, WindowedHistogram  # noqa: E402
+from repro.obs.metrics import percentile
+from repro.obs.timeseries import LogBucketHistogram, WindowedHistogram
 
 # latency/occupancy-like magnitudes: non-negative, wide dynamic range
 values = st.floats(min_value=0.0, max_value=1e6, allow_nan=False,
