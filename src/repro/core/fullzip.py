@@ -36,6 +36,13 @@ Fixed-stride columns additionally have a fused device gather route
 (``decode="pallas"``): the request-order fan-out permutation runs as one
 ``kernels.fullzip_gather`` block-table DMA gather over the unique zipped
 rows instead of a host permutation.
+
+A take is traced on the batch handle's tracer as one ``fullzip.take`` span
+whose host steps are child spans: ``fullzip.unique`` (deduplication),
+``fullzip.index`` (repetition-index decode), ``fullzip.unzip`` (entry decode
+and the codec) and ``fullzip.fanout`` (slicing rows and the request-order
+permutation); the store's ``store.read`` spans and the gather kernel's
+``kernel.*`` spans nest inside it.
 """
 
 from __future__ import annotations
@@ -44,6 +51,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..obs import NULL_TRACER
 from . import arrays as A
 from . import types as T
 from .compression import Encoded, get_bytes_codec, get_fixed_codec
@@ -64,6 +72,12 @@ from .rdlevels import (
 from .shred import ShreddedLeaf
 
 __all__ = ["encode_fullzip", "FullZipReader"]
+
+SPAN_TAKE = "fullzip.take"
+SPAN_UNIQUE = "fullzip.unique"
+SPAN_INDEX = "fullzip.index"
+SPAN_UNZIP = "fullzip.unzip"
+SPAN_FANOUT = "fullzip.fanout"
 
 
 def _len_field_width(max_len: int) -> int:
@@ -475,11 +489,17 @@ class FullZipReader(ColumnReader):
         order (duplicates materialized by the final permutation — a host
         permutation, or one device gather under ``decode='pallas'`` —
         never re-read)."""
+        tracer = getattr(io, "tracer", NULL_TRACER)
+        with tracer.span(SPAN_TAKE):
+            return self._take(rows, io, tracer)
+
+    def _take(self, rows: np.ndarray, io, tracer) -> ShreddedLeaf:
         rows = np.asarray(rows, dtype=np.int64)
         m = self.meta
         if len(rows) == 0:
             return empty_leaf(self.proto)
-        urows, inv = np.unique(rows, return_inverse=True)
+        with tracer.span(SPAN_UNIQUE):
+            urows, inv = np.unique(rows, return_inverse=True)
         if urows[0] < 0 or urows[-1] >= m["n_rows"]:
             raise IndexError(
                 f"take rows out of bounds for {m['n_rows']}-row column"
@@ -494,37 +514,42 @@ class FullZipReader(ColumnReader):
             # the decoded result, never re-read, so amplification stays >= 1
             io.note_useful(stride * n_unique)
             if self.decode == "pallas":
-                return self._take_fixed_pallas(data, n_unique, stride, inv)
-            rep, defs, vals = self._decode_fixed(data)
+                return self._take_fixed_pallas(data, n_unique, stride, inv,
+                                               tracer)
+            with tracer.span(SPAN_UNZIP):
+                rep, defs, vals = self._decode_fixed(data)
         else:
             if self.decode == "pallas":
                 # the rep-indexed path decodes variable-stride entries on the
                 # host frontier; the fused gather kernel needs fixed strides
-                tr = getattr(io, "tracer", None)
-                if tr is not None and tr.enabled:
-                    tr.fallback("fullzip", "variable-stride",
+                tracer.fallback("fullzip", "variable-stride",
                                 n_rows=int(n_unique))
             R = m["R"]
             # one IOP per row covers both adjacent index entries (start & end)
             idx, _ = io.read_many(
                 self.base + urows * R,
                 np.full(n_unique, 2 * R, dtype=np.int64), phase=0)
-            mat = idx.reshape(n_unique, 2 * R)
-            lo = _from_le(mat[:, :R]).astype(np.int64)
-            hi = _from_le(mat[:, R:]).astype(np.int64)
+            with tracer.span(SPAN_INDEX):
+                mat = idx.reshape(n_unique, 2 * R)
+                lo = _from_le(mat[:, :R]).astype(np.int64)
+                hi = _from_le(mat[:, R:]).astype(np.int64)
             data, _ = io.read_many(self.base + m["zip_base"] + lo, hi - lo,
                                    phase=1)
             # the fetched [lo, hi) spans are the row bounds: decode all rows
             # in lockstep instead of walking the concatenation per value
-            seg_offs = np.zeros(n_unique + 1, dtype=np.int64)
-            np.cumsum(hi - lo, out=seg_offs[1:])
-            rep, defs, vals = self._decode_entries(data, seg_offs=seg_offs)
+            with tracer.span(SPAN_UNZIP):
+                seg_offs = np.zeros(n_unique + 1, dtype=np.int64)
+                np.cumsum(hi - lo, out=seg_offs[1:])
+                rep, defs, vals = self._decode_entries(data,
+                                                       seg_offs=seg_offs)
             io.note_useful(int((hi - lo).sum()))
-        dec = leaf_slice(self.proto, rep, defs, vals, n_unique)
-        return reorder_leaf_rows(dec, inv)
+        with tracer.span(SPAN_FANOUT):
+            dec = leaf_slice(self.proto, rep, defs, vals, n_unique)
+            return reorder_leaf_rows(dec, inv)
 
     def _take_fixed_pallas(self, data: np.ndarray, n_unique: int, stride: int,
-                           inv: np.ndarray) -> ShreddedLeaf:
+                           inv: np.ndarray, tracer=NULL_TRACER
+                           ) -> ShreddedLeaf:
         """Fused gather route: one block-table DMA gather fans the unique
         zipped rows out to request order on device, then the request-order
         matrix is decoded strided — bit-identical to the host
@@ -533,9 +558,11 @@ class FullZipReader(ColumnReader):
 
         zipped = np.ascontiguousarray(data[: n_unique * stride]).reshape(
             n_unique, stride)
-        gathered = ops.fullzip_gather(zipped, inv.astype(np.int32))
-        rep, defs, vals = self._decode_fixed(gathered.reshape(-1))
-        return leaf_slice(self.proto, rep, defs, vals, len(inv))
+        gathered = ops.fullzip_gather(zipped, inv.astype(np.int32),
+                                      tracer=tracer)
+        with tracer.span(SPAN_UNZIP):
+            rep, defs, vals = self._decode_fixed(gathered.reshape(-1))
+            return leaf_slice(self.proto, rep, defs, vals, len(inv))
 
     def scan(self, io, io_chunk: int = 8 << 20) -> ShreddedLeaf:
         """Full scan in bounded-memory windows: each ``io_chunk`` window is

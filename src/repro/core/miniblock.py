@@ -28,6 +28,14 @@ power-of-two/8-aligned chunk rules make the kernel's static BlockSpec
 tiling possible), and a single segment-id permutation back to request
 order.  The logical IOPS/byte trace is identical to the historical per-row
 reader.
+
+A take is traced on the batch handle's tracer as one ``miniblock.take`` span
+whose host steps are child spans: ``miniblock.ranges`` (the repetition-index
+lookup and the chunk cover), ``miniblock.parse`` (splitting the fetched bytes
+into chunk buffers), ``miniblock.decode`` (host decode of the chunks the
+kernel does not take) and ``miniblock.select`` (row extraction and the
+request-order permutation); the store's ``store.read`` span and the decode
+kernel's ``kernel.*`` spans nest inside it.
 """
 
 from __future__ import annotations
@@ -37,6 +45,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..obs import NULL_TRACER
 from . import arrays as A
 from . import types as T
 from .compression import Encoded, get_bytes_codec, get_fixed_codec, min_bits
@@ -54,6 +63,12 @@ from .rdlevels import level_bits, pack_levels, unpack_levels
 from .shred import ShreddedLeaf
 
 __all__ = ["encode_miniblock", "MiniBlockReader"]
+
+SPAN_TAKE = "miniblock.take"
+SPAN_RANGES = "miniblock.ranges"
+SPAN_PARSE = "miniblock.parse"
+SPAN_DECODE = "miniblock.decode"
+SPAN_SELECT = "miniblock.select"
 
 MAX_CHUNK_VALUES = 4096
 TARGET_CHUNK_BYTES = 8 * 1024  # 1-2 disk sectors compressed
@@ -325,32 +340,47 @@ class MiniBlockReader(ColumnReader):
         return c0, c1, rows_before
 
     def take(self, rows: np.ndarray, io) -> ShreddedLeaf:
+        tracer = getattr(io, "tracer", NULL_TRACER)
+        with tracer.span(SPAN_TAKE):
+            return self._take(rows, io, tracer)
+
+    def _take(self, rows: np.ndarray, io, tracer) -> ShreddedLeaf:
         rows = np.asarray(rows, dtype=np.int64)
         if len(rows) == 0:
             return empty_leaf(self.proto)
-        urows, inv = np.unique(rows, return_inverse=True)
-        if urows[0] < 0 or urows[-1] >= self.meta["n_rows"]:
-            raise IndexError(
-                f"take rows out of bounds for {self.meta['n_rows']}-row column"
-            )
-        c0, c1, rows_before = self._chunk_ranges_for_rows(urows)
-        n_chunks = len(rows_before)
-        # union of the [c0, c1] ranges via a coverage diff (O(chunks + rows))
-        cover = np.zeros(n_chunks + 1, dtype=np.int64)
-        np.add.at(cover, c0, 1)
-        np.add.at(cover, c1 + 1, -1)
-        needed = np.nonzero(np.cumsum(cover[:-1]) > 0)[0]
+        with tracer.span(SPAN_RANGES):
+            urows, inv = np.unique(rows, return_inverse=True)
+            if urows[0] < 0 or urows[-1] >= self.meta["n_rows"]:
+                raise IndexError(
+                    f"take rows out of bounds for {self.meta['n_rows']}-row "
+                    "column")
+            c0, c1, rows_before = self._chunk_ranges_for_rows(urows)
+            n_chunks = len(rows_before)
+            # union of the [c0, c1] ranges via a coverage diff
+            # (O(chunks + rows))
+            cover = np.zeros(n_chunks + 1, dtype=np.int64)
+            np.add.at(cover, c0, 1)
+            np.add.at(cover, c1 + 1, -1)
+            needed = np.nonzero(np.cumsum(cover[:-1]) > 0)[0]
+            offs = np.asarray(self.meta["chunk_offsets"], dtype=np.int64)
+            sizes = np.array(
+                [self.meta["chunks"][c]["words"] * 8 for c in needed],
+                dtype=np.int64)
 
         # IO: every needed chunk exactly once, one phase-0 batch dispatch
-        offs = np.asarray(self.meta["chunk_offsets"], dtype=np.int64)
-        sizes = np.array([self.meta["chunks"][c]["words"] * 8 for c in needed],
-                         dtype=np.int64)
         data, doffs = io.read_many(self.base + offs[needed], sizes, phase=0)
-        raws = [data[doffs[i]: doffs[i + 1]] for i in range(len(needed))]
+        with tracer.span(SPAN_PARSE):
+            raws = [data[doffs[i]: doffs[i + 1]] for i in range(len(needed))]
 
         # decode each chunk exactly once (numpy or batched pallas)
-        decoded = self._decode_chunks(needed, raws,
-                                      tracer=getattr(io, "tracer", None))
+        decoded = self._decode_chunks(needed, raws, tracer=tracer)
+        with tracer.span(SPAN_SELECT):
+            return self._select(rows_before, needed, urows, inv, decoded, io)
+
+    def _select(self, rows_before, needed, urows, inv, decoded,
+                io) -> ShreddedLeaf:
+        """The requested rows' entries out of the decoded chunks, fanned out
+        to request order."""
         lens = np.array([self.meta["chunks"][c]["n_entries"] for c in needed],
                         dtype=np.int64)
         reps = [d[0] for d in decoded]
@@ -397,11 +427,14 @@ class MiniBlockReader(ColumnReader):
         rest fall back to the numpy path per chunk.  ``tracer`` (the IO
         path's, via the batch handle) receives a structured fallback-reason
         event for every chunk that routes back to numpy."""
+        tracer = tracer or NULL_TRACER
         if self.decode == "pallas":
             routed = self._decode_chunks_pallas(chunk_ids, raws, tracer)
             if routed is not None:
                 return routed
-        return [self._decode_chunk(c, raw) for c, raw in zip(chunk_ids, raws)]
+        with tracer.span(SPAN_DECODE):
+            return [self._decode_chunk(c, raw)
+                    for c, raw in zip(chunk_ids, raws)]
 
     _PALLAS_MAX_TILE_VALUES = 1 << 17  # VMEM cap on tile_entries * vpe
 
@@ -471,15 +504,17 @@ class MiniBlockReader(ColumnReader):
         return f"opaque-codec:{codec}"
 
     def _decode_chunks_pallas(self, chunk_ids, raws,
-                              tracer=None) -> Optional[List[tuple]]:
-        note = tracer is not None and tracer.enabled
+                              tracer=NULL_TRACER) -> Optional[List[tuple]]:
+        note = tracer.enabled
         col_reason = self._pallas_ineligible_reason()
         if col_reason is not None:
             if note:
                 tracer.fallback("miniblock", col_reason,
                                 n_chunks=len(chunk_ids))
             return None
-        from ..kernels import ops  # lazy: keep numpy-only readers jax-free
+        import jax  # lazy: keep numpy-only readers jax-free
+
+        from ..kernels import ops
         from ..kernels.miniblock_decode import MIN_TILE
 
         lt = self.proto.leaf_type
@@ -504,59 +539,76 @@ class MiniBlockReader(ColumnReader):
         if not any(p is not None for p in kp):
             return None
         sel = [i for i, p in enumerate(kp) if p is not None]
-        parsed = {i: _parse_chunk(raws[i]) for i in sel}
-        # power-of-two tiles of >= 1024 entries: whole (8, 128) vregs, and a
-        # handful of kernel shapes across batches
-        tile = max(MIN_TILE, ops.pow2(max(metas[i]["n_entries"] for i in sel)))
-        # chunk count and stream widths round up to powers of two (padding
-        # chunks decode to nothing), so batches share compiled shapes
-        n_pad = ops.pow2(len(sel))
-        params = np.zeros((n_pad, 3), dtype=np.int32)
-        streams = []  # (rep_words, def_words, val_words) ragged rows
-        for j, i in enumerate(sel):
-            cm, bufs = metas[i], parsed[i]
-            rw = ops.pack_words(bufs[0], pad_words=1) if rep_bits else None
-            dw = (ops.pack_words(bufs[1 if rep_bits else 0], pad_words=1)
-                  if def_bits else None)
-            vw = ops.pack_words(bufs[vbi], pad_words=1)
-            streams.append((rw, dw, vw))
-            params[j] = (cm["n_entries"], kp[i][0], kp[i][1])
+        with tracer.span(SPAN_PARSE):
+            parsed = {i: _parse_chunk(raws[i]) for i in sel}
+        sp = ops.MINIBLOCK_DECODE
+        with tracer.span(sp.pack):
+            # power-of-two tiles of >= 1024 entries: whole (8, 128) vregs,
+            # and a handful of kernel shapes across batches
+            tile = max(MIN_TILE,
+                       ops.pow2(max(metas[i]["n_entries"] for i in sel)))
+            # chunk count and stream widths round up to powers of two
+            # (padding chunks decode to nothing), so batches share compiled
+            # shapes
+            n_pad = ops.pow2(len(sel))
+            params = np.zeros((n_pad, 3), dtype=np.int32)
+            streams = []  # (rep_words, def_words, val_words) ragged rows
+            for j, i in enumerate(sel):
+                cm, bufs = metas[i], parsed[i]
+                rw = ops.pack_words(bufs[0], pad_words=1) if rep_bits else None
+                dw = (ops.pack_words(bufs[1 if rep_bits else 0], pad_words=1)
+                      if def_bits else None)
+                vw = ops.pack_words(bufs[vbi], pad_words=1)
+                streams.append((rw, dw, vw))
+                params[j] = (cm["n_entries"], kp[i][0], kp[i][1])
 
-        def stack(rows, active):
-            if not active:
-                return np.zeros((n_pad, 1), dtype=np.uint32)
-            width = ops.pow2(max(len(r) for r in rows))
-            out = np.zeros((n_pad, width), dtype=np.uint32)
-            for j, r in enumerate(rows):
-                out[j, : len(r)] = r
-            return out
+            def stack(rows, active):
+                if not active:
+                    return np.zeros((n_pad, 1), dtype=np.uint32)
+                width = ops.pow2(max(len(r) for r in rows))
+                out = np.zeros((n_pad, width), dtype=np.uint32)
+                for j, r in enumerate(rows):
+                    out[j, : len(r)] = r
+                return out
 
-        rep_np, def_np, vals_np = (np.asarray(a) for a in ops.miniblock_decode(
-            stack([s[0] for s in streams], rep_bits),
-            stack([s[1] for s in streams], def_bits),
-            stack([s[2] for s in streams], True),
-            params, rep_bits=rep_bits, def_bits=def_bits, vpe=vpe,
-            tile_entries=tile))
+            stacked = [stack([s[0] for s in streams], rep_bits),
+                       stack([s[1] for s in streams], def_bits),
+                       stack([s[2] for s in streams], True)]
+        outs = ops.miniblock_decode(
+            *stacked, params, rep_bits=rep_bits, def_bits=def_bits, vpe=vpe,
+            tile_entries=tile, tracer=tracer)
+        with tracer.span(sp.wait):
+            outs = jax.block_until_ready(outs)
+        with tracer.span(sp.d2h):
+            rep_np, def_np, vals_np = (np.asarray(a) for a in outs)
+        if tracer.enabled:
+            sp.count(tracer,
+                     d2h=rep_np.nbytes + def_np.nbytes + vals_np.nbytes,
+                     true=_decode_true_bytes(params, def_np, rep_bits,
+                                             def_bits, vpe))
 
         out: List[tuple] = [None] * len(chunk_ids)
-        for j, i in enumerate(sel):
-            k = metas[i]["n_entries"]
-            rep = rep_np[j, :k].astype(np.uint8) if rep_bits else None
-            defs = def_np[j, :k].astype(np.uint8) if def_bits else None
-            n_valid = int((defs == 0).sum()) if defs is not None else k
-            dense = vals_np[j, : n_valid * vpe].astype(dt)
-            if fsl:
-                vals = A.FixedSizeListArray(
-                    lt.with_nullable(False), np.ones(n_valid, bool),
-                    dense.reshape(n_valid, vpe),
-                )
-            else:
-                vals = A.PrimitiveArray(
-                    lt.with_nullable(False), np.ones(n_valid, bool), dense)
-            out[i] = (rep, defs, vals)
-        for i, p in enumerate(kp):
-            if p is None:
-                out[i] = self._decode_chunk(chunk_ids[i], raws[i])
+        with tracer.span(sp.unpack):
+            for j, i in enumerate(sel):
+                k = metas[i]["n_entries"]
+                rep = rep_np[j, :k].astype(np.uint8) if rep_bits else None
+                defs = def_np[j, :k].astype(np.uint8) if def_bits else None
+                n_valid = int((defs == 0).sum()) if defs is not None else k
+                dense = vals_np[j, : n_valid * vpe].astype(dt)
+                if fsl:
+                    vals = A.FixedSizeListArray(
+                        lt.with_nullable(False), np.ones(n_valid, bool),
+                        dense.reshape(n_valid, vpe),
+                    )
+                else:
+                    vals = A.PrimitiveArray(
+                        lt.with_nullable(False), np.ones(n_valid, bool),
+                        dense)
+                out[i] = (rep, defs, vals)
+        with tracer.span(SPAN_DECODE):
+            for i, p in enumerate(kp):
+                if p is None:
+                    out[i] = self._decode_chunk(chunk_ids[i], raws[i])
         return out
 
     def scan(self, io, io_chunk: int = 8 << 20) -> ShreddedLeaf:
@@ -583,6 +635,23 @@ class MiniBlockReader(ColumnReader):
         else:
             values = empty_values(self.proto.leaf_type)
         return leaf_slice(self.proto, rep, defs, values, self.meta["n_rows"])
+
+
+def _decode_true_bytes(params: np.ndarray, defs: np.ndarray, rep_bits: int,
+                       def_bits: int, vpe: int) -> int:
+    """The true bytes of one decode call: each chunk's encoded streams at
+    their packed widths plus the int32 levels and values decoded (padding
+    chunks have no entries and count nothing)."""
+    n, vbits = params[:, 0].astype(np.int64), params[:, 1].astype(np.int64)
+    if def_bits:
+        live = np.arange(defs.shape[1])[None, :] < n[:, None]
+        nv = vpe * ((defs == 0) & live).sum(1)
+    else:
+        nv = vpe * n
+    enc = (-(-n * rep_bits // 8) - (-n * def_bits // 8)
+           - (-nv * vbits // 8)).sum()
+    dec = 4 * (n * (rep_bits > 0) + n * (def_bits > 0) + nv).sum()
+    return int(enc + dec)
 
 
 # retained as the historical entry points; the implementations are the shared

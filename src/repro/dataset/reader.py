@@ -21,6 +21,11 @@ the dataset's concatenated global address space (see
 * the scheduler's :class:`~repro.store.WorkloadStats` watches the dataset's
   scan/take mix and auto-selects the admission policy of any cache level
   configured ``admission="auto"``.
+
+A take is one ``dataset.take:<column>`` span on the scheduler's tracer, with
+``dataset.locate`` (fragment routing and the per-fragment row sets), the leaf
+readers' spans, the batch's ``drain:*`` span and ``dataset.assemble``
+(stitching, request order and unshredding) inside it.
 """
 
 from __future__ import annotations
@@ -38,6 +43,9 @@ from ..core.shred import unshred
 from .manifest import Manifest, build_dataset_disk
 
 __all__ = ["DatasetReader"]
+
+SPAN_LOCATE = "dataset.locate"
+SPAN_ASSEMBLE = "dataset.assemble"
 
 
 class DatasetReader:
@@ -120,31 +128,36 @@ class DatasetReader:
         col = self.columns[name]
         if len(rows) == 0:
             return self.fragments[0].take(name, rows)
-        fi, local = self.locate(rows)
-        # concat order = request rows stably grouped by fragment; inv maps
-        # each request position to its row in that concatenation
-        perm = np.argsort(fi, kind="stable")
-        inv = np.empty(len(perm), dtype=np.int64)
-        inv[perm] = np.arange(len(perm), dtype=np.int64)
-        frag_ids = np.unique(fi)
-        with self.tracer.span(f"dataset.take:{name}", cat="reader",
-                              n_rows=len(rows), n_fragments=len(frag_ids)):
+        tracer = self.tracer
+        with tracer.span(f"dataset.take:{name}", cat="reader",
+                         n_rows=len(rows)) as span:
+            with tracer.span(SPAN_LOCATE):
+                fi, local = self.locate(rows)
+                # concat order = request rows stably grouped by fragment;
+                # inv maps each request position to its row in that
+                # concatenation
+                perm = np.argsort(fi, kind="stable")
+                inv = np.empty(len(perm), dtype=np.int64)
+                inv[perm] = np.arange(len(perm), dtype=np.int64)
+                frag_ids = np.unique(fi)
+                local_rows = [local[fi == f] for f in frag_ids]
+            span.set(n_fragments=len(frag_ids))
             with self.scheduler.batch(f"take:{name}") as io:
                 # the global rows are the logical requests this drain's
                 # modeled cost is attributed over (repro.obs.attrib)
                 io.note_requests(len(rows))
-                parts = [
-                    self.fragments[f].take_leaves(name, local[fi == f], io)
-                    for f in frag_ids
+                parts = [self.fragments[f].take_leaves(name, lr, io)
+                         for f, lr in zip(frag_ids, local_rows)]
+            with tracer.span(SPAN_ASSEMBLE):
+                if col["kind"] in ("arrow", "packed"):
+                    return A.concat(parts).take(inv)
+                n_leaves = len(parts[0])
+                leaves = [
+                    reorder_leaf_rows(concat_leaves([p[k] for p in parts]),
+                                      inv)
+                    for k in range(n_leaves)
                 ]
-            if col["kind"] in ("arrow", "packed"):
-                return A.concat(parts).take(inv)
-            n_leaves = len(parts[0])
-            leaves = [
-                reorder_leaf_rows(concat_leaves([p[k] for p in parts]), inv)
-                for k in range(n_leaves)
-            ]
-            return unshred(leaves, type_from_dict(col["type"]))
+                return unshred(leaves, type_from_dict(col["type"]))
 
     def scan(self, name: str, io_chunk: int = 8 << 20) -> A.Array:
         """Full-column scan across all fragments, in global row order."""

@@ -29,6 +29,7 @@ __all__ = ["bitunpack_pallas", "row_windows", "unpack_rows", "VALS_PER_BLOCK",
 LANES = 128
 ROWS_PER_BLOCK = 64
 VALS_PER_BLOCK = ROWS_PER_BLOCK * LANES  # 8192 values / grid step
+NAME = "bitunpack"  # the kernel's name and its ops' named scope
 
 
 def row_windows(words: jax.Array, bits, n_rows: int) -> jax.Array:
@@ -81,13 +82,15 @@ def bitunpack_pallas(words: jax.Array, bits: int, *, interpret: bool) -> jax.Arr
     wpb = VALS_PER_BLOCK * bits // 32
     assert words.shape[0] % wpb == 0, (words.shape, wpb)
     n_blocks = words.shape[0] // wpb
-    rows = row_windows(words, bits, n_blocks * ROWS_PER_BLOCK)
-    out = pl.pallas_call(
-        functools.partial(_kernel, bits=bits),
-        grid=(n_blocks,),
-        in_specs=[pl.BlockSpec((ROWS_PER_BLOCK, LANES), lambda b: (b, 0))],
-        out_specs=pl.BlockSpec((ROWS_PER_BLOCK, LANES), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct(rows.shape, jnp.uint32),
-        interpret=interpret,
-    )(rows)
-    return out.reshape(-1)
+    with jax.named_scope(NAME):
+        rows = row_windows(words, bits, n_blocks * ROWS_PER_BLOCK)
+        out = pl.pallas_call(
+            functools.partial(_kernel, bits=bits),
+            grid=(n_blocks,),
+            in_specs=[pl.BlockSpec((ROWS_PER_BLOCK, LANES), lambda b: (b, 0))],
+            out_specs=pl.BlockSpec((ROWS_PER_BLOCK, LANES), lambda b: (b, 0)),
+            out_shape=jax.ShapeDtypeStruct(rows.shape, jnp.uint32),
+            interpret=interpret,
+            name=NAME,
+        )(rows)
+        return out.reshape(-1)

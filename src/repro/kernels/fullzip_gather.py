@@ -31,6 +31,7 @@ __all__ = ["fullzip_gather_pallas", "ROWS_PER_STEP", "ROW_WORDS"]
 
 ROWS_PER_STEP = 128  # row copies in flight per grid step (one SMEM row of ids)
 ROW_WORDS = 128  # a row copy moves whole 128-word HBM tiles
+NAME = "fullzip_gather"  # the kernel's name and its ops' named scope
 
 
 def _kernel(idx_ref, zipped_ref, out_ref, sem):
@@ -65,20 +66,22 @@ def fullzip_gather_pallas(
     assert width % ROW_WORDS == 0, width
     n_take = rows.shape[0]
     steps = max(1, -(-n_take // ROWS_PER_STEP))
-    ids = jnp.zeros(steps * ROWS_PER_STEP, jnp.int32).at[:n_take].set(
-        rows.astype(jnp.int32)).reshape(steps, 1, ROWS_PER_STEP)
-    out = pl.pallas_call(
-        _kernel,
-        grid=(steps,),
-        in_specs=[
-            pl.BlockSpec((1, 1, ROWS_PER_STEP), lambda i: (i, 0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct((steps * ROWS_PER_STEP, 1, width),
-                                       jnp.uint32),
-        scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
-        interpret=interpret,
-    )(ids, words.reshape(n_rows, 1, width))
-    return out.reshape(-1, width)[:n_take]
+    with jax.named_scope(NAME):
+        ids = jnp.zeros(steps * ROWS_PER_STEP, jnp.int32).at[:n_take].set(
+            rows.astype(jnp.int32)).reshape(steps, 1, ROWS_PER_STEP)
+        out = pl.pallas_call(
+            _kernel,
+            grid=(steps,),
+            in_specs=[
+                pl.BlockSpec((1, 1, ROWS_PER_STEP), lambda i: (i, 0, 0),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            out_shape=jax.ShapeDtypeStruct((steps * ROWS_PER_STEP, 1, width),
+                                           jnp.uint32),
+            scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
+            interpret=interpret,
+            name=NAME,
+        )(ids, words.reshape(n_rows, 1, width))
+        return out.reshape(-1, width)[:n_take]

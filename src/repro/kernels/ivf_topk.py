@@ -36,6 +36,7 @@ __all__ = ["ivf_topk_pallas", "cand_tile", "QUERY_TILE", "K_PAD"]
 
 QUERY_TILE = 8   # f32 min sublane tile: one grid step scores 8 queries
 K_PAD = 128      # output lane width; k <= K_PAD, columns >= k are sentinel
+NAME = "ivf_topk"  # the kernel's name and its ops' named scope
 _TILE_BYTES = 2 << 20  # one candidate tile in VMEM (double-buffered)
 
 
@@ -103,24 +104,26 @@ def ivf_topk_pallas(queries: jax.Array, cands: jax.Array, ids: jax.Array,
     tn = cand_tile(np_, dp)
     assert qp % QUERY_TILE == 0 and dp % 128 == 0 and np_ % tn == 0
     assert 1 <= k <= K_PAD
-    cc = jnp.sum(cands * cands, axis=1)[None, :]                    # (1, Np)
-    out_spec = pl.BlockSpec((QUERY_TILE, K_PAD), lambda i, j: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_kernel, k=k),
-        grid=(qp // QUERY_TILE, np_ // tn),
-        in_specs=[
-            pl.BlockSpec((QUERY_TILE, dp), lambda i, j: (i, 0)),
-            pl.BlockSpec((tn, dp), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, tn), lambda i, j: (0, j)),
-            pl.BlockSpec((1, tn), lambda i, j: (0, j)),
-            pl.BlockSpec((QUERY_TILE, tn), lambda i, j: (i, j)),
-        ],
-        out_specs=[out_spec, out_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((qp, K_PAD), jnp.float32),
-            jax.ShapeDtypeStruct((qp, K_PAD), jnp.int32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(queries, cands, cc, ids, mask)
+    with jax.named_scope(NAME):
+        cc = jnp.sum(cands * cands, axis=1)[None, :]                # (1, Np)
+        out_spec = pl.BlockSpec((QUERY_TILE, K_PAD), lambda i, j: (i, 0))
+        return pl.pallas_call(
+            functools.partial(_kernel, k=k),
+            grid=(qp // QUERY_TILE, np_ // tn),
+            in_specs=[
+                pl.BlockSpec((QUERY_TILE, dp), lambda i, j: (i, 0)),
+                pl.BlockSpec((tn, dp), lambda i, j: (j, 0)),
+                pl.BlockSpec((1, tn), lambda i, j: (0, j)),
+                pl.BlockSpec((1, tn), lambda i, j: (0, j)),
+                pl.BlockSpec((QUERY_TILE, tn), lambda i, j: (i, j)),
+            ],
+            out_specs=[out_spec, out_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((qp, K_PAD), jnp.float32),
+                jax.ShapeDtypeStruct((qp, K_PAD), jnp.int32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+            name=NAME,
+        )(queries, cands, cc, ids, mask)

@@ -43,6 +43,7 @@ __all__ = ["miniblock_decode_pallas", "MAX_ENTRIES", "MIN_TILE"]
 
 MAX_ENTRIES = 4096  # the format's per-chunk value ceiling (sec 4.2.1)
 MIN_TILE = 8 * LANES  # tiles are whole (8, 128) int32 vregs
+NAME = "miniblock_decode"  # the kernel's name and its ops' named scope
 
 
 def _kernel(params_ref, *refs, rep_bits: int, def_bits: int, vpe: int):
@@ -95,25 +96,29 @@ def miniblock_decode_pallas(
     assert tile_entries % MIN_TILE == 0, tile_entries
     C = params.shape[0]
     R = tile_entries // LANES
-    streams = [row_windows(w, b, R) for w, b in
-               ((rep_words, rep_bits), (def_words, def_bits)) if b]
-    streams.append(row_windows(val_words, params[:, 1], R * vpe))
-    n_levels = len(streams) - 1
-    spec = lambda r: pl.BlockSpec((1, r, LANES), lambda c, p: (c, 0, 0))  # noqa: E731
-    outs = pl.pallas_call(
-        functools.partial(_kernel, rep_bits=rep_bits, def_bits=def_bits,
-                          vpe=vpe),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(C,),
-            in_specs=[spec(s.shape[1]) for s in streams],
-            out_specs=[spec(s.shape[1]) for s in streams],
-        ),
-        out_shape=[jax.ShapeDtypeStruct(s.shape, jnp.int32) for s in streams],
-        interpret=interpret,
-    )(params, *streams)
-    levels = iter(o.reshape(C, tile_entries) for o in outs[:n_levels])
-    zeros = jnp.zeros((C, tile_entries), jnp.int32)
-    rep = next(levels) if rep_bits else zeros
-    defs = next(levels) if def_bits else zeros
-    return rep, defs, outs[-1].reshape(C, tile_entries * vpe)
+    with jax.named_scope(NAME):
+        streams = [row_windows(w, b, R) for w, b in
+                   ((rep_words, rep_bits), (def_words, def_bits)) if b]
+        streams.append(row_windows(val_words, params[:, 1], R * vpe))
+        n_levels = len(streams) - 1
+        spec = lambda r: pl.BlockSpec(  # noqa: E731
+            (1, r, LANES), lambda c, p: (c, 0, 0))
+        outs = pl.pallas_call(
+            functools.partial(_kernel, rep_bits=rep_bits, def_bits=def_bits,
+                              vpe=vpe),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(C,),
+                in_specs=[spec(s.shape[1]) for s in streams],
+                out_specs=[spec(s.shape[1]) for s in streams],
+            ),
+            out_shape=[jax.ShapeDtypeStruct(s.shape, jnp.int32)
+                       for s in streams],
+            interpret=interpret,
+            name=NAME,
+        )(params, *streams)
+        levels = iter(o.reshape(C, tile_entries) for o in outs[:n_levels])
+        zeros = jnp.zeros((C, tile_entries), jnp.int32)
+        rep = next(levels) if rep_bits else zeros
+        defs = next(levels) if def_bits else zeros
+        return rep, defs, outs[-1].reshape(C, tile_entries * vpe)

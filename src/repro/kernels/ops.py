@@ -3,14 +3,30 @@
 Each op dispatches to the Pallas TPU kernel or to the pure-jnp oracle in
 ``ref.py``.  On a TPU the kernel lowers to Mosaic; on any other backend it
 runs in interpret mode, so the CPU test suite executes the kernel *body*.
+
+Kernel dispatch is traced step by step on the caller's tracer (``tracer=``;
+the no-op :data:`~repro.obs.NULL_TRACER` by default), each span named
+``kernel.<step>:<kernel>`` (:class:`KernelSpans`): ``pack`` (host padding
+and packing), ``h2d`` (the inputs' transfer to the device), ``launch`` (the
+dispatch of the jitted program), ``wait`` (an explicit
+``jax.block_until_ready`` on the outputs), ``d2h`` (their copy to the host)
+and ``unpack`` (slicing and views back).  Where the caller copies the outputs
+back (``ivf_topk``, ``miniblock_decode``, ``bitunpack`` return device
+arrays), the caller opens ``wait``, ``d2h`` and ``unpack``.  An enabled
+tracer also counts the bytes each kernel moves: ``kernel.bytes_h2d.<kernel>``
+and ``kernel.bytes_d2h.<kernel>`` (padded, as transferred) and
+``kernel.bytes_true.<kernel>`` (the call's true bytes, read and written).
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import NULL_TRACER
 from . import ref
 from .bitunpack import VALS_PER_BLOCK, bitunpack_pallas
 from .fullzip_gather import ROW_WORDS, fullzip_gather_pallas
@@ -19,6 +35,11 @@ from .miniblock_decode import MAX_ENTRIES, miniblock_decode_pallas
 from .ref import IVF_ID_SENTINEL
 
 __all__ = [
+    "KernelSpans",
+    "IVF_TOPK",
+    "MINIBLOCK_DECODE",
+    "FULLZIP_GATHER",
+    "BITUNPACK",
     "bitunpack",
     "miniblock_decode",
     "fullzip_gather",
@@ -30,8 +51,44 @@ __all__ = [
 ]
 
 
+class KernelSpans(NamedTuple):
+    """The span names of one kernel's dispatch steps."""
+
+    kernel: str
+    pack: str
+    h2d: str
+    launch: str
+    wait: str
+    d2h: str
+    unpack: str
+
+    @classmethod
+    def of(cls, kernel: str) -> "KernelSpans":
+        return cls(kernel, *(f"kernel.{step}:{kernel}"
+                             for step in cls._fields[1:]))
+
+    def count(self, tracer, h2d: int = 0, d2h: int = 0, true: int = 0) -> None:
+        """Add the call's bytes to the kernel's transfer counters (call only
+        with ``tracer.enabled``)."""
+        for kind, n in (("h2d", h2d), ("d2h", d2h), ("true", true)):
+            if n:
+                tracer.count(f"kernel.bytes_{kind}.{self.kernel}", int(n))
+
+
+IVF_TOPK = KernelSpans.of("ivf_topk")
+MINIBLOCK_DECODE = KernelSpans.of("miniblock_decode")
+FULLZIP_GATHER = KernelSpans.of("fullzip_gather")
+BITUNPACK = KernelSpans.of("bitunpack")
+
+
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _host_bytes(*arrays) -> int:
+    """Bytes of the host arrays among ``arrays``: what ``jnp.asarray``
+    copies to the device (device arrays stay where they are)."""
+    return sum(a.nbytes for a in arrays if isinstance(a, np.ndarray))
 
 
 def pack_words(buf: np.ndarray, pad_words: int = 1) -> np.ndarray:
@@ -45,16 +102,25 @@ def pack_words(buf: np.ndarray, pad_words: int = 1) -> np.ndarray:
     return w
 
 
-def bitunpack(words: jax.Array, n: int, bits: int, *, use_pallas: bool = True) -> jax.Array:
+def bitunpack(words: jax.Array, n: int, bits: int, *, use_pallas: bool = True,
+              tracer=None) -> jax.Array:
     """Unpack ``n`` ``bits``-wide values from a uint32 word stream."""
     if not use_pallas:
         return ref.bitunpack_ref(words, n, bits)
+    tracer = tracer or NULL_TRACER
+    sp = BITUNPACK
     wpb = VALS_PER_BLOCK * bits // 32
     n_blocks = max(1, -(-n // VALS_PER_BLOCK))
     need = n_blocks * wpb
-    w = jnp.pad(words, (0, max(0, need - words.shape[0])))[:need]
-    out = bitunpack_pallas(w, bits, interpret=not on_tpu())
-    return out[:n]
+    with tracer.span(sp.h2d):
+        w = jnp.asarray(words)
+    with tracer.span(sp.launch):
+        w = jnp.pad(w, (0, max(0, need - w.shape[0])))[:need]
+        out = bitunpack_pallas(w, bits, interpret=not on_tpu())[:n]
+    if tracer.enabled:
+        sp.count(tracer, h2d=_host_bytes(words),
+                 true=-(-n * bits // 8) + 4 * n)
+    return out
 
 
 def miniblock_decode(
@@ -68,6 +134,7 @@ def miniblock_decode(
     vpe: int = 1,
     tile_entries: int = MAX_ENTRIES,
     use_pallas: bool = True,
+    tracer=None,
 ):
     """Decode C mini-block chunks -> ``(rep, defs, vals)`` int32 tiles.
 
@@ -75,7 +142,9 @@ def miniblock_decode(
     ``n_entries``; ``vals`` is ``(C, tile_entries * vpe)``: the chunk's
     values in stream order (``vpe`` values per valid entry — fixed-size-list
     chunks set it to the list size), zero past the last one.
-    ``tile_entries`` is a multiple of 1024.
+    ``tile_entries`` is a multiple of 1024.  The outputs stay on the device:
+    the caller waits for and copies them (``kernel.wait``/``d2h``/``unpack``
+    of :data:`MINIBLOCK_DECODE`).
     """
     if not use_pallas:
         return ref.miniblock_decode_ref(
@@ -83,14 +152,22 @@ def miniblock_decode(
             params[:, 0], params[:, 1], params[:, 2],
             tile_entries, rep_bits, def_bits, vpe,
         )
-    return miniblock_decode_pallas(
-        rep_words, def_words, val_words, params,
-        rep_bits=rep_bits, def_bits=def_bits, vpe=vpe,
-        tile_entries=tile_entries, interpret=not on_tpu(),
-    )
+    tracer = tracer or NULL_TRACER
+    sp = MINIBLOCK_DECODE
+    args = (rep_words, def_words, val_words, params)
+    with tracer.span(sp.h2d):
+        ins = [jnp.asarray(a) for a in args]
+    with tracer.span(sp.launch):
+        out = miniblock_decode_pallas(
+            *ins, rep_bits=rep_bits, def_bits=def_bits, vpe=vpe,
+            tile_entries=tile_entries, interpret=not on_tpu(),
+        )
+    if tracer.enabled:
+        sp.count(tracer, h2d=_host_bytes(*args))
+    return out
 
 
-def fullzip_gather(zipped, rows, *, use_pallas: bool = True):
+def fullzip_gather(zipped, rows, *, use_pallas: bool = True, tracer=None):
     """Gather zipped fixed-stride rows (the §4.1 take path).
 
     ``zipped`` is (n_rows, ...) of any dtype (the take path's rows are
@@ -100,20 +177,35 @@ def fullzip_gather(zipped, rows, *, use_pallas: bool = True):
     """
     if not use_pallas:
         return ref.fullzip_gather_ref(zipped, rows)
-    z = np.ascontiguousarray(np.asarray(zipped))
-    row = z.reshape(len(z), -1).view(np.uint8)
-    nbytes = row.shape[1]
-    # row counts round up to powers of two so takes share compiled shapes
-    padded = np.zeros((pow2(len(z)),
-                       -(-nbytes // (4 * ROW_WORDS)) * 4 * ROW_WORDS), np.uint8)
-    padded[: len(z), :nbytes] = row
-    ids = np.zeros(pow2(len(rows)), np.int32)
-    ids[: len(rows)] = np.asarray(rows)
-    out = fullzip_gather_pallas(jnp.asarray(padded.view(np.uint32)),
-                                jnp.asarray(ids), interpret=not on_tpu())
-    got = np.ascontiguousarray(
-        np.asarray(out)[: len(rows)].view(np.uint8)[:, :nbytes])
-    return got.view(z.dtype).reshape((-1,) + z.shape[1:])
+    tracer = tracer or NULL_TRACER
+    sp = FULLZIP_GATHER
+    with tracer.span(sp.pack):
+        z = np.ascontiguousarray(np.asarray(zipped))
+        row = z.reshape(len(z), -1).view(np.uint8)
+        nbytes = row.shape[1]
+        # row counts round up to powers of two so takes share compiled shapes
+        padded = np.zeros((pow2(len(z)),
+                           -(-nbytes // (4 * ROW_WORDS)) * 4 * ROW_WORDS),
+                          np.uint8)
+        padded[: len(z), :nbytes] = row
+        ids = np.zeros(pow2(len(rows)), np.int32)
+        ids[: len(rows)] = np.asarray(rows)
+    with tracer.span(sp.h2d):
+        words_d, ids_d = jnp.asarray(padded.view(np.uint32)), jnp.asarray(ids)
+    with tracer.span(sp.launch):
+        out = fullzip_gather_pallas(words_d, ids_d, interpret=not on_tpu())
+    with tracer.span(sp.wait):
+        out = jax.block_until_ready(out)
+    with tracer.span(sp.d2h):
+        out = np.asarray(out)
+    with tracer.span(sp.unpack):
+        got = np.ascontiguousarray(out[: len(rows)].view(np.uint8)[:, :nbytes])
+        got = got.view(z.dtype).reshape((-1,) + z.shape[1:])
+    if tracer.enabled:
+        # true bytes as the roofline counts them: each row read and written
+        sp.count(tracer, h2d=padded.nbytes + ids.nbytes, d2h=out.nbytes,
+                 true=2 * len(rows) * nbytes)
+    return got
 
 
 def pow2(n: int) -> int:
@@ -169,48 +261,59 @@ def ivf_topk(queries, cands, ids, k: int, mask=None, *,
     within 31 bits, k <= 128, at least one candidate); otherwise falls
     back to the jnp oracle and reports the structured reason through
     ``tracer`` as a ``decode.fallback.ivf.<reason>`` counter — the same
-    no-silent-fallback contract as the decode kernels.
+    no-silent-fallback contract as the decode kernels.  The outputs stay on
+    the device: the caller waits for and copies them (``kernel.wait``/
+    ``d2h``/``unpack`` of :data:`IVF_TOPK`).
     """
     k = int(k)
     if k < 1:
         raise ValueError("k must be positive")
-    q2 = np.atleast_2d(np.asarray(queries))
-    c2 = np.atleast_2d(np.asarray(cands))
-    ids_arr = np.asarray(ids).reshape(-1)
-    qn, n = q2.shape[0], c2.shape[0]
-    reason = None
-    if q2.dtype != np.float32 or c2.dtype != np.float32:
-        reason = "non-float32"
-    elif n == 0:
-        reason = "no-candidates"
-    elif k > K_PAD:
-        reason = f">{K_PAD}-k"
-    elif ids_arr.size and int(ids_arr.max()) >= IVF_ID_SENTINEL:
-        reason = ">31-bit-ids"
-    wide = reason == ">31-bit-ids"
-    if wide:
-        # jnp is int32 on CPU: select over *positions* of the candidates
-        # sorted by id (position tie-break == id tie-break) and map back
-        order = np.argsort(ids_arr, kind="stable")
-        c2 = c2[order]
-        if mask is not None:
-            mask = np.atleast_2d(np.asarray(mask))[:, order]
-        ids_sorted, ids_run = ids_arr[order], np.arange(n, dtype=np.int32)
-    else:
-        ids_run = ids_arr if ids_arr.dtype == np.int32 \
-            else ids_arr.astype(np.int32)
-    _, qpad, cpad, idp, mpad = _ivf_pad(q2, c2, ids_run, mask)
-    if not use_pallas or reason is not None:
-        if use_pallas and tracer is not None:
-            tracer.fallback("ivf", reason, n_queries=qn, n_candidates=n, k=k)
-        d, w = ref.ivf_topk_ref(jnp.asarray(qpad), jnp.asarray(cpad),
-                                jnp.asarray(idp), jnp.asarray(mpad),
-                                k, kp=max(K_PAD, k))
-    else:
-        d, w = ivf_topk_pallas(jnp.asarray(qpad), jnp.asarray(cpad),
-                               jnp.asarray(idp), jnp.asarray(mpad),
-                               k=k, interpret=not on_tpu())
-    d, w = d[:qn, :k], w[:qn, :k]
+    tracer = tracer or NULL_TRACER
+    sp = IVF_TOPK
+    with tracer.span(sp.pack):
+        q2 = np.atleast_2d(np.asarray(queries))
+        c2 = np.atleast_2d(np.asarray(cands))
+        ids_arr = np.asarray(ids).reshape(-1)
+        qn, n = q2.shape[0], c2.shape[0]
+        reason = None
+        if q2.dtype != np.float32 or c2.dtype != np.float32:
+            reason = "non-float32"
+        elif n == 0:
+            reason = "no-candidates"
+        elif k > K_PAD:
+            reason = f">{K_PAD}-k"
+        elif ids_arr.size and int(ids_arr.max()) >= IVF_ID_SENTINEL:
+            reason = ">31-bit-ids"
+        wide = reason == ">31-bit-ids"
+        if wide:
+            # jnp is int32 on CPU: select over *positions* of the candidates
+            # sorted by id (position tie-break == id tie-break) and map back
+            order = np.argsort(ids_arr, kind="stable")
+            c2 = c2[order]
+            if mask is not None:
+                mask = np.atleast_2d(np.asarray(mask))[:, order]
+            ids_sorted, ids_run = ids_arr[order], np.arange(n, dtype=np.int32)
+        else:
+            ids_run = ids_arr if ids_arr.dtype == np.int32 \
+                else ids_arr.astype(np.int32)
+        _, qpad, cpad, idp, mpad = _ivf_pad(q2, c2, ids_run, mask)
+    with tracer.span(sp.h2d):
+        ins = [jnp.asarray(a) for a in (qpad, cpad, idp, mpad)]
+    with tracer.span(sp.launch):
+        if not use_pallas or reason is not None:
+            if use_pallas:
+                tracer.fallback("ivf", reason, n_queries=qn, n_candidates=n,
+                                k=k)
+            d, w = ref.ivf_topk_ref(*ins, k, kp=max(K_PAD, k))
+        else:
+            d, w = ivf_topk_pallas(*ins, k=k, interpret=not on_tpu())
+        d, w = d[:qn, :k], w[:qn, :k]
+    if tracer.enabled:
+        # true bytes as the roofline counts them: queries, candidates and
+        # ids at the true shapes, and the (Q, k) results
+        d_true = q2.shape[1]
+        sp.count(tracer, h2d=_host_bytes(qpad, cpad, idp, mpad),
+                 true=4 * (qn * d_true + n * d_true + n) + 8 * qn * k)
     if wide:
         wnp = np.asarray(w)
         w = np.where(wnp == IVF_ID_SENTINEL, np.int64(IVF_ID_SENTINEL),

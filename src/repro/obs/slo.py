@@ -221,7 +221,7 @@ class SLOMonitor:
         return self.registry.counter_values("slo.breach.")
 
     def table(self) -> List[Dict]:
-        """Per-tenant summary rows (the obs_report SLO table)."""
+        """Per-tenant summary rows (the bench artifact's SLO table)."""
         rows = []
         for tenant in sorted(self.objectives):
             obj = self.objectives[tenant]

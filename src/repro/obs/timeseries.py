@@ -39,8 +39,7 @@ before creating anything — and an *enabled* plane is purely observational:
 priced times and logical IOPS are bit-identical with sampling on or off
 (tested).  Exporters: Perfetto counter tracks (``"C"`` events on the
 virtual clock) into a :class:`~repro.obs.trace.Tracer`, a Prometheus text
-dump, and a JSON form the bench artifacts embed for
-``tools/obs_report.py``'s terminal dashboard.
+dump, and a JSON form the bench artifacts embed.
 
 Like the rest of ``repro.obs`` this module imports nothing from the wider
 package (``metrics`` only), so every layer above can depend on it.
@@ -406,7 +405,7 @@ class MetricsPlane:
 
     def export(self, max_points: int = 256) -> Dict:
         """The JSON form embedded in bench artifacts (NaN-free by
-        construction) and rendered by ``tools/obs_report.py``."""
+        construction)."""
         return {
             "series": {name: g.export(max_points)
                        for name, g in sorted(self.series.items())},

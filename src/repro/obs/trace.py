@@ -24,12 +24,16 @@ objects** and appends nothing.  Instrumented code never needs an ``if``:
 (tested): logical IOPS/bytes and every priced time are bit-identical whether
 tracing is on or off — the tracer observes the pipeline, it never steers it.
 
-Timestamps are host-wall microseconds since tracer construction
-(``time.perf_counter``): they time the *simulation's* orchestration work.
-The modelled device time lives in span ``args`` where the instrumentation
-site provides it.  :meth:`Tracer.export` writes the standard
+Two sinks.  The default, ``sink="memory"``, keeps events in memory:
+timestamps are host-wall microseconds since tracer construction
+(``time.perf_counter``), and :meth:`Tracer.export` writes the standard
 ``{"traceEvents": [...]}`` JSON object form — open it at
-https://ui.perfetto.dev or ``chrome://tracing``.
+https://ui.perfetto.dev or ``chrome://tracing``.  The modelled device time
+lives in span ``args`` where the instrumentation site provides it.
+``sink="profiler"`` writes each span as a ``jax.profiler.TraceAnnotation``
+of its name (no args), so program spans land on the profiler's clock beside
+the device's operations while a ``jax.profiler`` trace is running; it keeps
+no events, and its counters (``metrics``) still count.
 """
 
 from __future__ import annotations
@@ -40,7 +44,7 @@ from typing import Dict, List, Optional
 
 from .metrics import MetricsRegistry
 
-__all__ = ["Tracer", "NullTracer", "NULL_TRACER", "NULL_SPAN"]
+__all__ = ["Tracer", "NullTracer", "NULL_TRACER", "NULL_SPAN", "SINKS"]
 
 
 class _NullSpan:
@@ -95,6 +99,30 @@ class _Span:
         return False
 
 
+class _ProfilerSpan:
+    """One open span of the profiler sink: a ``TraceAnnotation`` of the
+    span's name that swallows ``set()`` calls."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, annotation, name: str):
+        self._ann = annotation(name)
+
+    def __enter__(self) -> "_ProfilerSpan":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
+
+SINKS = ("memory", "profiler")
+
+
 class Tracer:
     """Collects Chrome-trace events; ``enabled=False`` is a strict no-op.
 
@@ -103,16 +131,27 @@ class Tracer:
     shares it.  ``metrics`` is a :class:`~repro.obs.metrics.MetricsRegistry`
     fed alongside the event list (fallback-reason counters, span-less
     counts) so tests can query aggregates without parsing the trace.
+    ``sink`` picks where spans go (see the module docstring): ``"memory"``
+    or ``"profiler"``.
     """
 
-    def __init__(self, enabled: bool = True, pid: int = 1, tid: int = 1):
+    def __init__(self, enabled: bool = True, pid: int = 1, tid: int = 1,
+                 sink: str = "memory"):
+        if sink not in SINKS:
+            raise ValueError(f"sink must be one of {SINKS}, got {sink!r}")
         self.enabled = bool(enabled)
+        self.sink = sink
         self.pid = pid
         self.tid = tid
         self.events: List[Dict] = []
         self.metrics = MetricsRegistry()
         self._tracks: Dict[str, int] = {}
         self._t0 = time.perf_counter()
+        self._annotation = None
+        if sink == "profiler" and self.enabled:
+            import jax  # lazy: obs imports no accelerator at module level
+
+            self._annotation = jax.profiler.TraceAnnotation
 
     def _now_us(self) -> float:
         return (time.perf_counter() - self._t0) * 1e6
@@ -124,9 +163,11 @@ class Tracer:
         tracer's default track — the scheduler uses one track per request so
         concurrent takers render as separate Perfetto lanes.  Returns the
         shared :data:`NULL_SPAN` when disabled — no allocation, no
-        recording."""
+        recording.  The profiler sink records the name only."""
         if not self.enabled:
             return NULL_SPAN
+        if self._annotation is not None:
+            return _ProfilerSpan(self._annotation, name)
         return _Span(self, name, cat, args, tid=tid)
 
     def track(self, key: Optional[str]) -> int:
@@ -134,9 +175,9 @@ class Tracer:
 
         The first time a key is seen a Chrome ``thread_name`` metadata event
         is emitted so Perfetto labels the lane with the request id; repeat
-        calls return the same tid.  ``None`` (or disabled) falls back to the
-        tracer's default track."""
-        if not self.enabled or key is None:
+        calls return the same tid.  ``None`` (or disabled, or the profiler
+        sink) falls back to the tracer's default track."""
+        if not self.enabled or key is None or self._annotation is not None:
             return self.tid
         tid = self._tracks.get(key)
         if tid is None:
@@ -149,8 +190,9 @@ class Tracer:
         return tid
 
     def instant(self, name: str, cat: str = "event", **args) -> None:
-        """A structured point event (thread-scoped instant)."""
-        if not self.enabled:
+        """A structured point event (thread-scoped instant); the profiler
+        sink drops it."""
+        if not self.enabled or self._annotation is not None:
             return
         self.events.append({
             "name": name, "cat": cat, "ph": "i", "s": "t",
@@ -165,8 +207,9 @@ class Tracer:
         timestamp with an explicit microsecond value — the metrics plane
         uses this to replay virtual-clock gauge series as counter tracks
         (``MetricsPlane.to_trace``) so they line up with the simulated
-        timeline rather than orchestration wall time."""
-        if not self.enabled:
+        timeline rather than orchestration wall time.  The profiler sink
+        drops it."""
+        if not self.enabled or self._annotation is not None:
             return
         self.events.append({
             "name": name, "cat": cat, "ph": "C",
@@ -181,12 +224,19 @@ class Tracer:
         (``miniblock``/``fullzip``), ``reason`` a stable slug
         (``float-values``, ``variable-width-leaf``, ``>31-bit``,
         ``opaque-codec:<name>``, ...).  Counted in ``metrics`` under
-        ``decode.fallback.<encoding>.<reason>`` for test/CI queries."""
+        ``decode.fallback.<encoding>.<reason>`` for test/CI queries (by
+        either sink)."""
         if not self.enabled:
             return
         self.metrics.counter(f"decode.fallback.{encoding}.{reason}").inc()
         self.instant("pallas_fallback", cat="decode",
                      encoding=encoding, reason=reason, **args)
+
+    def count(self, name: str, n: int = 1) -> None:
+        """Add ``n`` to the ``metrics`` counter ``name`` (either sink);
+        nothing when disabled."""
+        if self.enabled:
+            self.metrics.counter(name).inc(n)
 
     # -- export --------------------------------------------------------------
     def trace_events(self) -> Dict:

@@ -18,6 +18,14 @@ from ..obs import NULL_TRACER
 
 __all__ = ["BatchedEngine", "Retriever", "SearchResult"]
 
+# the steps of one search, each a span inside ``search``
+SPAN_PROBE = "serve.probe"
+SPAN_POSTINGS = "serve.postings"
+SPAN_MASK = "serve.mask"
+SPAN_CANDIDATES = "serve.candidates"
+SPAN_TOPK = "serve.topk"
+SPAN_WINNERS = "serve.winners"
+
 
 @dataclasses.dataclass
 class GenResult:
@@ -157,6 +165,12 @@ class Retriever:
         and ties break toward the lowest row id.  The numpy and Pallas
         distance routes (``decode`` knob) each agree with float64 top-k up
         to ties within :func:`repro.kernels.ref.topk_tolerance`.
+
+        Traced as one ``search`` span holding a span per step
+        (``serve.probe``, ``serve.postings``, ``serve.mask``,
+        ``serve.candidates``, ``serve.topk``, ``serve.winners``); the
+        top-k kernel's outputs are waited for and copied back in
+        ``serve.probe`` and ``serve.topk``.
         """
         if self.index is None:
             raise ValueError(
@@ -168,40 +182,62 @@ class Retriever:
         nprobe = min(max(1, int(nprobe)), p)
         use_pallas = self.decode != "numpy"
         tracer = getattr(self.reader, "tracer", NULL_TRACER)
+        sp = ops.IVF_TOPK
         with tracer.span("search", cat="serve", n_queries=nq, k=k,
                          nprobe=nprobe):
             # 1. probe: nearest centroids per query (centroid rows come
             # through the shared store; warm after the first search)
-            cent = self.index.centroids(index_version)
-            _, probes = ops.ivf_topk(
-                q, cent, np.arange(p, dtype=np.int32), nprobe,
-                use_pallas=use_pallas, tracer=tracer)
-            probes = np.asarray(probes, np.int64)           # (Q, nprobe)
+            with tracer.span(SPAN_PROBE):
+                cent = self.index.centroids(index_version)
+                _, probes = ops.ivf_topk(
+                    q, cent, np.arange(p, dtype=np.int32), nprobe,
+                    use_pallas=use_pallas, tracer=tracer)
+                with tracer.span(sp.wait):
+                    probes = jax.block_until_ready(probes)
+                with tracer.span(sp.d2h):
+                    probes = np.asarray(probes)
+                if tracer.enabled:
+                    sp.count(tracer, d2h=probes.nbytes)
+                with tracer.span(sp.unpack):
+                    probes = probes.astype(np.int64)        # (Q, nprobe)
             # 2. one batched posting fetch for the union of probed parts
-            parts = np.unique(probes)
-            posts = self.index.postings(parts, index_version)
-            cand_ids = np.concatenate(posts) if posts else \
-                np.zeros(0, np.int64)
+            with tracer.span(SPAN_POSTINGS):
+                parts = np.unique(probes)
+                posts = self.index.postings(parts, index_version)
+                cand_ids = np.concatenate(posts) if posts else \
+                    np.zeros(0, np.int64)
             # per-query eligibility: candidate row -> owning partition,
             # eligible iff that partition is in the query's probe set
-            probed = np.zeros((nq, p), bool)
-            probed[np.repeat(np.arange(nq), nprobe), probes.reshape(-1)] = True
-            part_of = np.repeat(parts, [len(pl) for pl in posts])
-            mask = probed[:, part_of]                       # (Q, N)
+            with tracer.span(SPAN_MASK):
+                probed = np.zeros((nq, p), bool)
+                probed[np.repeat(np.arange(nq), nprobe),
+                       probes.reshape(-1)] = True
+                part_of = np.repeat(parts, [len(pl) for pl in posts])
+                mask = probed[:, part_of]                   # (Q, N)
             # 3. one batched take of the candidate vectors, then the kernel
-            cand = self.reader.take(self.column, cand_ids)
-            d, w = ops.ivf_topk(q, np.asarray(cand.values, np.float32),
-                                cand_ids, k, mask=mask,
-                                use_pallas=use_pallas, tracer=tracer)
-            d = np.asarray(d, np.float32)
-            w = np.asarray(w, np.int64)
-            w[w == ops.IVF_ID_SENTINEL] = -1
+            with tracer.span(SPAN_CANDIDATES):
+                cand = self.reader.take(self.column, cand_ids)
+            with tracer.span(SPAN_TOPK):
+                d, w = ops.ivf_topk(q, np.asarray(cand.values, np.float32),
+                                    cand_ids, k, mask=mask,
+                                    use_pallas=use_pallas, tracer=tracer)
+                with tracer.span(sp.wait):
+                    d, w = jax.block_until_ready((d, w))
+                with tracer.span(sp.d2h):
+                    d, w = np.asarray(d), np.asarray(w)
+                if tracer.enabled:
+                    sp.count(tracer, d2h=d.nbytes + w.nbytes)
+                with tracer.span(sp.unpack):
+                    d = np.asarray(d, np.float32)
+                    w = np.asarray(w, np.int64)
+                    w[w == ops.IVF_ID_SENTINEL] = -1
             # 4. one batched take of the deduplicated winner rows — the
             # response payload, served (and priced) like any data read
-            winners = np.unique(w[w >= 0])
-            values = None
-            if fetch and winners.size:
-                values = self.reader.take(self.column, winners)
+            with tracer.span(SPAN_WINNERS):
+                winners = np.unique(w[w >= 0])
+                values = None
+                if fetch and winners.size:
+                    values = self.reader.take(self.column, winners)
             return SearchResult(ids=w, distances=d, probes=probes,
                                 winner_rows=winners, values=values,
                                 n_candidates=int(cand_ids.size))

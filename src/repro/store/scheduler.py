@@ -392,9 +392,17 @@ class TieredStore:
             lvl.cache.drop()
 
 
+SPAN_READ = "store.read"
+
+
 class ReadBatch:
     """Handle for one ``take``/``scan``'s reads.  Serves bytes synchronously
-    and records the logical trace; dispatch happens when the batch closes."""
+    and records the logical trace; dispatch happens when the batch closes.
+
+    Each :meth:`read`/:meth:`read_many` is one ``store.read`` span (recording
+    the logical ops and copying the bytes out of the disk image: host work,
+    not modelled IO), counted under ``store.read_spans`` and
+    ``store.read_bytes`` when the tracer is enabled."""
 
     def __init__(self, scheduler: "IOScheduler", label: str = "io",
                  prefetch: bool = False):
@@ -416,9 +424,15 @@ class ReadBatch:
     def read(self, offset: int, size: int, phase: int = 0) -> np.ndarray:
         if self._closed:
             raise RuntimeError("read on a closed ReadBatch")
-        offset, size = int(offset), int(size)
-        self.ops.append((offset, size, phase))
-        return self.scheduler.store.disk.read(offset, size)
+        tr = self.scheduler.tracer
+        with tr.span(SPAN_READ):
+            offset, size = int(offset), int(size)
+            self.ops.append((offset, size, phase))
+            data = self.scheduler.store.disk.read(offset, size)
+        if tr.enabled:
+            tr.count("store.read_spans")
+            tr.count("store.read_bytes", size)
+        return data
 
     def read_many(self, offsets, sizes, phase: int = 0):
         """Submit one phase-grouped batch of spans in a single dispatch.
@@ -432,13 +446,21 @@ class ReadBatch:
         """
         if self._closed:
             raise RuntimeError("read on a closed ReadBatch")
-        offsets = np.asarray(offsets, dtype=np.int64)
-        sizes = np.asarray(sizes, dtype=np.int64)
-        phase = int(phase)
-        self.ops.extend(
-            (o, s, phase) for o, s in zip(offsets.tolist(), sizes.tolist())
-        )
-        return self.scheduler.store.disk.read_gather(offsets, sizes)
+        tr = self.scheduler.tracer
+        with tr.span(SPAN_READ):
+            offsets = np.asarray(offsets, dtype=np.int64)
+            sizes = np.asarray(sizes, dtype=np.int64)
+            phase = int(phase)
+            self.ops.extend(
+                (o, s, phase)
+                for o, s in zip(offsets.tolist(), sizes.tolist())
+            )
+            data, out_offsets = self.scheduler.store.disk.read_gather(
+                offsets, sizes)
+        if tr.enabled:
+            tr.count("store.read_spans", len(sizes))
+            tr.count("store.read_bytes", int(out_offsets[-1]))
+        return data, out_offsets
 
     def note_useful(self, nbytes: int) -> None:
         self._useful += int(nbytes)

@@ -9,6 +9,7 @@ from repro.kernels import ops
 from repro.kernels.ivf_topk import cand_tile
 from repro.kernels.miniblock_decode import MAX_ENTRIES
 from repro.kernels.ref import topk_mismatches, topk_tolerance
+from repro.obs import Tracer
 
 rng = np.random.default_rng(0)
 
@@ -145,12 +146,11 @@ def test_kernel_matches_host_miniblock_column():
 # ---------------------------------------------------------------------------
 
 
-class _FallbackRecorder:
-    """Minimal tracer surface for the ops-layer fallback hook."""
-
-    enabled = True
+class _FallbackRecorder(Tracer):
+    """A tracer that records the ops layer's fallback calls."""
 
     def __init__(self):
+        super().__init__()
         self.calls = []
 
     def fallback(self, encoding, reason, **args):

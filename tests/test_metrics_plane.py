@@ -497,7 +497,7 @@ def test_zipf_slo_objectives_from_tenant_spec():
 
 
 # ---------------------------------------------------------------------------
-# tools: bench_gate slo strictness, bench_history, obs_report
+# tools: bench_gate slo strictness
 # ---------------------------------------------------------------------------
 
 
@@ -517,62 +517,3 @@ def test_bench_gate_slo_keys_always_strict(bench_gate):
     # outside an slo path the same key is still rate-skipped
     assert bench_gate.compare({"x": {"requests_per_s": 1}},
                               {"x": {"requests_per_s": 9}}) == []
-
-
-def test_bench_history_collect_and_idempotent_append(tmp_path):
-    hist = _load_module(ROOT / "tools" / "bench_history.py",
-                        "bench_history_mp")
-    art = {"meta": {"run": {"git_sha": "abc1234", "smoke": True,
-                            "timestamp": "2026-08-07T00:00:00Z"}},
-           "headline": {"p99_ms": 1.5},
-           "slo": {"healthy_breaches": {},
-                   "degraded": {"detection_delay_s": 0.12,
-                                "breaches": {"slo.breach.premium": 1}}}}
-    (tmp_path / "BENCH_serve.json").write_text(json.dumps(art))
-    row = hist.collect(str(tmp_path))
-    assert row["run"]["git_sha"] == "abc1234"
-    assert row["benches"]["serve"]["headline"] == {"p99_ms": 1.5}
-    assert row["benches"]["serve"]["slo"]["detection_delay_s"] == 0.12
-    out = tmp_path / "traj.jsonl"
-    assert hist.append(row, str(out)) is True
-    assert hist.append(row, str(out)) is False          # same run: skipped
-    assert hist.append(row, str(out), force=True) is True
-    lines = [json.loads(x) for x in out.read_text().splitlines() if x]
-    assert len(lines) == 2 and lines[0] == lines[1]
-
-
-def test_obs_report_renders_sparklines_and_slo_table(tmp_path):
-    rep = _load_module(ROOT / "tools" / "obs_report.py", "obs_report_mp")
-    assert rep.sparkline([0.0, 0.5, 1.0], lo=0.0, hi=1.0) == "▁▄█"
-    assert rep.sparkline([2.0, 2.0]) == "▁▁"
-    assert len(rep.sparkline(list(range(1000)), width=48)) == 48
-    art = {
-        "meta": {"run": {"git_sha": "abc", "smoke": True,
-                         "timestamp": "t"}},
-        "metrics_plane": {
-            "series": {"tier.nvme.utilization":
-                       {"t": [0.1, 0.2], "v": [0.1, 1.0], "n_samples": 2}},
-            "latency": {"latency.p": {"count": 3, "p50": 0.01, "p99": 0.02,
-                                      "max": 0.03}},
-            "counters": {"slo.breach.p": 1},
-        },
-        "slo": {"degraded": {"t_degradation_s": 0.3,
-                             "detection_delay_s": 0.1,
-                             "table": [{"tenant": "p", "objective_ms": 50.0,
-                                        "target": 0.99, "requests": 10,
-                                        "bad": 2, "bad_fraction": 0.2,
-                                        "breaches": 1,
-                                        "first_alert_t": 0.4}]}},
-    }
-    text = rep.render(art)
-    assert "tier.nvme.utilization" in text and "█" in text
-    assert "latency.p" in text
-    assert "slo.breach.p=1" in text
-    assert "20.0%" in text and "0.400" in text   # SLO table row rendered
-    # empty artifact degrades gracefully
-    assert "no metrics_plane" in rep.render({})
-    p = tmp_path / "BENCH_serve.json"
-    p.write_text(json.dumps(art))
-    out = tmp_path / "report.txt"
-    assert rep.main([str(p), "--out", str(out)]) == 0
-    assert out.read_text() == text
