@@ -25,8 +25,18 @@ __all__ = [
     "Disk", "DiskView", "IOTracker", "IOStats", "DeviceModel", "Degradation",
     "TransientErrors", "Blackout", "CorrelatedFault",
     "NVME", "S3", "HBM", "DRAM", "model_time", "merge_phase_extents",
-    "trace_stats",
+    "trace_stats", "window_width",
 ]
+
+
+def window_width(sizes: np.ndarray) -> int:
+    """The size every span of a gather shares, or 0 where the spans differ
+    in size, are empty or there are none: an in-memory
+    :meth:`Disk.read_gather` serves a nonzero width as a row-window copy."""
+    if len(sizes) == 0:
+        return 0
+    width = int(sizes[0])
+    return width if width > 0 and bool((sizes == width).all()) else 0
 
 
 class Disk:
@@ -121,7 +131,10 @@ class Disk:
         ``data[out_offsets[k]:out_offsets[k + 1]]``.  Bounds are checked for
         every span; the in-memory path is a single fancy-index copy (no
         per-span Python loop), which is what makes the batched ``take``
-        pipeline's chunk/index/span fetches cheap.
+        pipeline's chunk/index/span fetches cheap.  Spans that all share one
+        positive size (fixed-width full-zip rows) are copied as rows of a
+        window view, one index per span; ragged spans take an index per
+        byte.
         """
         offsets = np.asarray(offsets, dtype=np.int64)
         sizes = np.asarray(sizes, dtype=np.int64)
@@ -139,6 +152,12 @@ class Disk:
             parts = [self.read(int(o), int(s)) for o, s in zip(offsets, sizes)]
             data = np.concatenate(parts) if parts else np.zeros(0, np.uint8)
             return data, out_offs
+        width = window_width(sizes)
+        if width:
+            rows = np.lib.stride_tricks.sliding_window_view(
+                self._mem[: self._size], width)
+            # fancy indexing copies, so the result never aliases the image
+            return rows[offsets].reshape(-1), out_offs
         total = int(out_offs[-1])
         idx = np.repeat(offsets - out_offs[:-1], sizes) + np.arange(
             total, dtype=np.int64

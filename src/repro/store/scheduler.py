@@ -38,6 +38,7 @@ from ..core.io_sim import (
     IOStats,
     merge_phase_extents,
     trace_stats,
+    window_width,
 )
 from ..obs.timeseries import NULL_PLANE, MetricsPlane
 from ..obs.trace import NULL_TRACER
@@ -402,7 +403,9 @@ class ReadBatch:
     Each :meth:`read`/:meth:`read_many` is one ``store.read`` span (recording
     the logical ops and copying the bytes out of the disk image: host work,
     not modelled IO), counted under ``store.read_spans`` and
-    ``store.read_bytes`` when the tracer is enabled."""
+    ``store.read_bytes`` when the tracer is enabled; ``read_many`` spans
+    that share one size, served by a row-window copy, are also counted
+    under ``store.read_window_spans``."""
 
     def __init__(self, scheduler: "IOScheduler", label: str = "io",
                  prefetch: bool = False):
@@ -460,6 +463,8 @@ class ReadBatch:
         if tr.enabled:
             tr.count("store.read_spans", len(sizes))
             tr.count("store.read_bytes", int(out_offsets[-1]))
+            if window_width(sizes):
+                tr.count("store.read_window_spans", len(sizes))
         return data, out_offsets
 
     def note_useful(self, nbytes: int) -> None:

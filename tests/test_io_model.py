@@ -123,3 +123,81 @@ def test_disk_read_bounds_and_copies(tmp_path):
         got[:] = 0  # must not corrupt the backing store
         np.testing.assert_array_equal(disk.read(8, 8), payload[8:16])
         assert disk.read(64, 0).size == 0  # empty read at EOF is legal
+
+
+def _grown_disk():
+    """A disk grown past its first buffer: the backing array is larger than
+    the logical size, so only ``_size`` bounds a read."""
+    from repro.core.io_sim import Disk
+
+    disk = Disk(np.arange(200, dtype=np.uint8))
+    disk.grow(56)
+    disk.write(200, np.arange(56, dtype=np.uint8) + 100)
+    assert len(disk._mem) > len(disk) == 256
+    return disk
+
+
+def _payload_disk():
+    from repro.core.io_sim import Disk
+
+    return Disk(np.random.default_rng(7).integers(0, 256, 256, dtype=np.uint8))
+
+
+# (disk, offsets, sizes, raises): offsets and sizes are within a 256-byte
+# image, which the view cases place at a nonzero base of a larger disk
+_GATHER_CASES = {
+    "uniform": (_payload_disk, [0, 40, 96, 200], [16] * 4, False),
+    "ragged": (_payload_disk, [3, 50, 120], [5, 17, 1], False),
+    "single": (_payload_disk, [10], [33], False),
+    "overlap-dup-uniform": (_payload_disk, [8, 12, 12, 8], [16] * 4, False),
+    "overlap-dup-ragged": (_payload_disk, [8, 12, 12, 8], [16, 4, 30, 16],
+                           False),
+    "unsorted-uniform": (_payload_disk, [200, 5, 130, 64], [24] * 4, False),
+    "unsorted-ragged": (_payload_disk, [200, 5, 130], [24, 3, 9], False),
+    "last-byte-uniform": (_payload_disk, [240, 0], [16, 16], False),
+    "last-byte-ragged": (_payload_disk, [250, 0], [6, 3], False),
+    "grown": (_grown_disk, [190, 240, 100], [16] * 3, False),
+    "grown-past-size-uniform": (_grown_disk, [0, 250], [8, 8], True),
+    "grown-past-size-ragged": (_grown_disk, [0, 250], [4, 8], True),
+    "zero-size-uniform": (_payload_disk, [0, 256, 17], [0, 0, 0], False),
+    "zero-size-mixed": (_payload_disk, [0, 30, 256], [4, 0, 0], False),
+    "negative-offset-uniform": (_payload_disk, [-1, 8], [4, 4], True),
+    "empty": (_payload_disk, [], [], False),
+}
+
+
+@pytest.mark.parametrize("case", list(_GATHER_CASES))
+def test_read_gather_matches_per_span_reads(case):
+    """``read_gather`` on a disk and on a view returns the concatenation of
+    per-span ``Disk.read`` results with their offsets, whichever copy serves
+    it (row window for spans of one size, byte index otherwise), raises the
+    same bounds error, and hands back a writable copy that never aliases
+    the image."""
+    from repro.core.io_sim import Disk, DiskView
+
+    make, offsets, sizes, raises = _GATHER_CASES[case]
+    disk = make()
+    image = disk.read(0, len(disk))
+    base = 13
+    outer = Disk(np.concatenate([np.full(base, 0xEE, np.uint8), image,
+                                 np.full(9, 0xDD, np.uint8)]))
+    for store, kind in ((disk, "disk"), (DiskView(outer, base, len(disk)),
+                                         "view")):
+        if raises:
+            with pytest.raises(ValueError,
+                               match=f"gather read out of bounds for "
+                                     f"{len(disk)}-byte {kind}"):
+                store.read_gather(offsets, sizes)
+            continue
+        want = [disk.read(o, s) for o, s in zip(offsets, sizes)]
+        data, out_offs = store.read_gather(offsets, sizes)
+        assert data.dtype == np.uint8 and data.ndim == 1
+        np.testing.assert_array_equal(
+            data, np.concatenate(want) if want else np.zeros(0, np.uint8))
+        np.testing.assert_array_equal(
+            out_offs, np.concatenate([[0], np.cumsum(sizes, dtype=np.int64)]))
+        assert data.flags.writeable
+        backing = store.disk if kind == "view" else store
+        assert not np.shares_memory(data, backing._mem)
+        data[:] = 0xAB  # must not corrupt the backing store
+        np.testing.assert_array_equal(store.read(0, len(disk)), image)

@@ -349,6 +349,50 @@ def test_batch_rejects_use_after_close():
     assert sched.stats().n_iops == 1
 
 
+@pytest.mark.parametrize("spans", ["uniform", "ragged"])
+def test_read_many_accounting_matches_per_span_reads(spans):
+    """``read_many`` records the same logical ops, drains and prices as the
+    same spans issued one ``read`` at a time, however ``read_gather``
+    copies the bytes; ``store.read_window_spans`` counts exactly the spans
+    of calls whose spans share one size."""
+    from repro.obs import Tracer
+
+    rng = np.random.default_rng(3)
+    image = rng.integers(0, 256, 1 << 18, dtype=np.uint8)
+    n = 300
+    sizes = (np.full(n, 516) if spans == "uniform"
+             else rng.integers(0, 900, n))
+    offsets = rng.integers(0, len(image) - 900, n)  # unsorted, with repeats
+
+    def run(batched):
+        tracer = Tracer()
+        store = TieredStore.cached(Disk(image.copy()), cache_bytes=8 * 4096)
+        sched = IOScheduler(store, tracer=tracer)
+        ops, got = [], []
+        for phase in (0, 1):  # the second batch meets a warm cache
+            with sched.batch("t") as io:
+                if batched:
+                    data, offs = io.read_many(offsets, sizes, phase=phase)
+                    got += [data[offs[k]:offs[k + 1]] for k in range(n)]
+                else:
+                    got += [io.read(o, s, phase=phase)
+                            for o, s in zip(offsets, sizes)]
+            ops.append(list(io.ops))
+        return (ops, got, store.drain_log, sched.tier_stats(),
+                sched.model_time(), tracer.metrics.counter_values())
+
+    ops, got, drains, tiers, t, counts = run(batched=True)
+    ops1, got1, drains1, tiers1, t1, counts1 = run(batched=False)
+    assert ops == ops1
+    assert all(np.array_equal(a, b) for a, b in zip(got, got1))
+    assert drains == drains1 and tiers == tiers1 and t == t1
+    assert counts["store.read_spans"] == counts1["store.read_spans"] == 2 * n
+    assert counts["store.read_bytes"] == counts1["store.read_bytes"]
+    window = 2 * n if spans == "uniform" else 0
+    assert counts.get("store.read_window_spans", 0) == window
+    assert "store.read_window_spans" not in counts1
+
+
 # ---------------------------------------------------------------------------
 # write path: dirty blocks, flush policies, durability accounting
 # ---------------------------------------------------------------------------
