@@ -30,7 +30,8 @@ from .arrow_like import ArrowReader, encode_arrow
 from .encodings_base import EncodedColumn
 from .fullzip import FullZipReader, encode_fullzip
 from .io_sim import Disk
-from .miniblock import MiniBlockReader, encode_miniblock
+from .miniblock import (ChunkPlan, MiniBlockReader, decode_plans,
+                        encode_miniblock)
 from .packing import PackedStructReader, encode_packed_struct
 from .parquet_like import ParquetReader, encode_parquet
 from .shred import ShreddedLeaf, leaf_paths, shred, unshred
@@ -378,20 +379,26 @@ class FileReader:
                 # in take_leaves — so a dataset-wide take counts each row
                 # once, not once per fragment
                 io.note_requests(len(rows))
-                res = self.take_leaves(name, rows, io)
+                plans: List[ChunkPlan] = []
+                res = self.take_leaves(name, rows, io, plans)
+                decode_plans(plans, self.tracer)
+                res = self.finish_leaves(res, io)
             if col["kind"] in ("arrow", "packed"):
                 return res
             return unshred(res, type_from_dict(col["type"]))
 
-    def take_leaves(self, name: str, rows, io):
-        """One take through an externally-owned batch handle.
+    def take_leaves(self, name: str, rows, io, plans: List[ChunkPlan]):
+        """One take's reads through an externally-owned batch handle.
 
         Returns the final :class:`~repro.core.arrays.Array` for
-        arrow/packed columns, or the list of per-leaf ``ShreddedLeaf``
-        slices (request order, duplicates materialized) for shredded ones —
-        the dataset layer concatenates leaves across fragments before
-        unshredding once.  Reads are rebased by this file's ``base`` so a
-        shared batch prices them in the global address space.
+        arrow/packed columns, or one slot per leaf for shredded ones.
+        Mini-block leaves are read but not decoded: each leaf's
+        :class:`~repro.core.miniblock.ChunkPlan` is appended to ``plans``
+        and stands in its slot.  The caller decodes every plan of the batch
+        at once (:func:`~repro.core.miniblock.decode_plans`) and then turns
+        the slots into leaves with :meth:`finish_leaves`, inside the same
+        batch.  Reads are rebased by this file's ``base`` so a shared batch
+        prices them in the global address space.
         """
         rows = np.asarray(rows, dtype=np.int64)
         col = self.columns[name]
@@ -399,7 +406,25 @@ class FileReader:
         io = io.at(self.base)
         if col["kind"] in ("arrow", "packed"):
             return readers[0].take(rows, io)
-        return [r.take(rows, io) for r in readers]
+        out = []
+        for r in readers:
+            if isinstance(r, MiniBlockReader):
+                out.append(r.plan_take(rows, io))
+                plans.append(out[-1])
+            else:
+                out.append(r.take(rows, io))
+        return out
+
+    def finish_leaves(self, res, io):
+        """A :meth:`take_leaves` result after its plans are decoded: the
+        arrow/packed array as it is, or the per-leaf ``ShreddedLeaf``
+        slices (request order, duplicates materialized), which the dataset
+        layer concatenates across fragments before unshredding once."""
+        if not isinstance(res, list):
+            return res
+        io = io.at(self.base)
+        return [x.reader.select(x, io) if isinstance(x, ChunkPlan) else x
+                for x in res]
 
     def scan(self, name: str, io_chunk: int = 8 << 20) -> A.Array:
         with self.tracer.span(f"scan:{name}", cat="reader",

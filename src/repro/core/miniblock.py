@@ -29,18 +29,21 @@ tiling possible), and a single segment-id permutation back to request
 order.  The logical IOPS/byte trace is identical to the historical per-row
 reader.
 
-A take is traced on the batch handle's tracer as one ``miniblock.take`` span
-whose host steps are child spans: ``miniblock.ranges`` (the repetition-index
-lookup and the chunk cover), ``miniblock.parse`` (splitting the fetched bytes
-into chunk buffers), ``miniblock.decode`` (host decode of the chunks the
-kernel does not take) and ``miniblock.select`` (row extraction and the
-request-order permutation); the store's ``store.read`` span and the decode
-kernel's ``kernel.*`` spans nest inside it.
+A take is traced on the batch handle's tracer as three ``miniblock.take``
+spans, one per step, whose host steps are child spans: the read
+(``miniblock.ranges``, the repetition-index lookup and the chunk cover, the
+store's ``store.read`` and ``miniblock.parse``, splitting the fetched bytes
+into chunk buffers), the decode (inside a ``miniblock.decode_batch`` span:
+the decode kernel's ``kernel.*`` spans and ``miniblock.decode``, host decode
+of the chunks the kernel does not take) and the select (``miniblock.select``,
+row extraction and the request-order permutation).  A multi-column take
+runs the read and the select per leaf and one decode for all its leaves.
 """
 
 from __future__ import annotations
 
 import struct
+from dataclasses import dataclass
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -62,13 +65,14 @@ from .encodings_base import (
 from .rdlevels import level_bits, pack_levels, unpack_levels
 from .shred import ShreddedLeaf
 
-__all__ = ["encode_miniblock", "MiniBlockReader"]
+__all__ = ["encode_miniblock", "MiniBlockReader", "ChunkPlan", "decode_plans"]
 
 SPAN_TAKE = "miniblock.take"
 SPAN_RANGES = "miniblock.ranges"
 SPAN_PARSE = "miniblock.parse"
 SPAN_DECODE = "miniblock.decode"
 SPAN_SELECT = "miniblock.select"
+SPAN_DECODE_BATCH = "miniblock.decode_batch"
 
 MAX_CHUNK_VALUES = 4096
 TARGET_CHUNK_BYTES = 8 * 1024  # 1-2 disk sectors compressed
@@ -276,21 +280,45 @@ def encode_miniblock(
     )
 
 
+@dataclass
+class ChunkPlan:
+    """One mini-block leaf's chunks between their read and their decode.
+
+    ``chunk_ids`` and their parsed buffers ``bufs``; ``kernel``: the
+    decode kernel's per-chunk ``(bits, ref)``, None for a chunk the host
+    decodes (``kernel`` itself is None when no chunk is on the kernel
+    route); ``lookup``: a take's ``(rows_before, urows, inv)`` for
+    :meth:`MiniBlockReader.select` (None for an empty take).
+    :func:`decode_plans` fills ``decoded`` with each chunk's
+    ``(rep, defs, values)``.
+    """
+
+    reader: "MiniBlockReader"
+    chunk_ids: np.ndarray
+    bufs: List[List[np.ndarray]]
+    kernel: Optional[List[Optional[tuple]]]
+    lookup: Optional[tuple] = None
+    decoded: Optional[List[tuple]] = None
+
+
 class MiniBlockReader(ColumnReader):
     """Mini-block random access + scan.
 
-    ``take`` runs as a batched, decode-once pipeline: one vectorized
-    ``searchsorted`` maps all requested rows to chunk ranges, every needed
-    chunk is fetched in a single phase-0 :meth:`~repro.store.ReadBatch.read_many`
-    dispatch and decoded exactly once, row extraction is a single
-    segment-id/gather permutation over the concatenated entry streams, and
-    the result is fanned back out to request order with one
-    :func:`~repro.core.encodings_base.reorder_leaf_rows` pass.
+    ``take`` runs as a batched, decode-once pipeline in three steps, which
+    a multi-column take (``DatasetReader.take_columns``) runs for many
+    leaves at once: :meth:`plan_take` (one vectorized ``searchsorted`` maps
+    all requested rows to chunk ranges, every needed chunk is fetched in a
+    single phase-0 :meth:`~repro.store.ReadBatch.read_many` dispatch and
+    parsed), :func:`decode_plans` (each chunk decoded exactly once) and
+    :meth:`select` (row extraction as a single segment-id/gather
+    permutation over the concatenated entry streams, fanned back out to
+    request order with one :func:`~repro.core.encodings_base.reorder_leaf_rows`
+    pass).
 
     ``decode`` selects the chunk decoder: ``"numpy"`` (host) or ``"pallas"``
-    (the `repro.kernels` mini-block kernel; bit-packed flat integer chunks
-    are batch-decoded in one ``pallas_call``, other codecs fall back to
-    numpy per chunk).
+    (the `repro.kernels` mini-block kernel; bit-packed and FoR byte-packed
+    integer chunks are batch-decoded in one ``pallas_call`` per level class,
+    other codecs fall back to numpy per chunk).
     """
 
     def __init__(self, meta: Dict, base: int, leaf_proto: ShreddedLeaf,
@@ -300,9 +328,8 @@ class MiniBlockReader(ColumnReader):
             raise ValueError(f"decode must be 'numpy'|'pallas', got {decode!r}")
         self.decode = decode
 
-    def _decode_chunk(self, ci: int, raw: np.ndarray):
+    def _decode_chunk(self, ci: int, bufs: List[np.ndarray]):
         cm = self.meta["chunks"][ci]
-        bufs = _parse_chunk(raw)
         k = cm["n_entries"]
         bi = 0
         rep = defs = None
@@ -340,42 +367,58 @@ class MiniBlockReader(ColumnReader):
         return c0, c1, rows_before
 
     def take(self, rows: np.ndarray, io) -> ShreddedLeaf:
+        plan = self.plan_take(rows, io)
+        decode_plans([plan], getattr(io, "tracer", NULL_TRACER))
+        return self.select(plan, io)
+
+    def plan_take(self, rows: np.ndarray, io) -> ChunkPlan:
+        """A take's read step: the chunks that hold ``rows``, read in one
+        phase-0 dispatch and parsed, not yet decoded."""
+        tracer = getattr(io, "tracer", NULL_TRACER)
+        rows = np.asarray(rows, dtype=np.int64)
+        with tracer.span(SPAN_TAKE):
+            if len(rows) == 0:
+                return ChunkPlan(self, np.zeros(0, np.int64), [], None)
+            with tracer.span(SPAN_RANGES):
+                urows, inv = np.unique(rows, return_inverse=True)
+                if urows[0] < 0 or urows[-1] >= self.meta["n_rows"]:
+                    raise IndexError(
+                        f"take rows out of bounds for {self.meta['n_rows']}-"
+                        "row column")
+                c0, c1, rows_before = self._chunk_ranges_for_rows(urows)
+                n_chunks = len(rows_before)
+                # union of the [c0, c1] ranges via a coverage diff
+                # (O(chunks + rows))
+                cover = np.zeros(n_chunks + 1, dtype=np.int64)
+                np.add.at(cover, c0, 1)
+                np.add.at(cover, c1 + 1, -1)
+                needed = np.nonzero(np.cumsum(cover[:-1]) > 0)[0]
+                offs = np.asarray(self.meta["chunk_offsets"], dtype=np.int64)
+                sizes = np.array(
+                    [self.meta["chunks"][c]["words"] * 8 for c in needed],
+                    dtype=np.int64)
+
+            # IO: every needed chunk exactly once, one phase-0 batch dispatch
+            data, doffs = io.read_many(self.base + offs[needed], sizes,
+                                       phase=0)
+            with tracer.span(SPAN_PARSE):
+                bufs = [_parse_chunk(data[doffs[i]: doffs[i + 1]])
+                        for i in range(len(needed))]
+            return ChunkPlan(self, needed, bufs,
+                             self._kernel_params(needed, tracer),
+                             lookup=(rows_before, urows, inv))
+
+    def select(self, plan: ChunkPlan, io) -> ShreddedLeaf:
+        """A take's select step: the requested rows out of the plan's
+        decoded chunks, in request order."""
         tracer = getattr(io, "tracer", NULL_TRACER)
         with tracer.span(SPAN_TAKE):
-            return self._take(rows, io, tracer)
-
-    def _take(self, rows: np.ndarray, io, tracer) -> ShreddedLeaf:
-        rows = np.asarray(rows, dtype=np.int64)
-        if len(rows) == 0:
-            return empty_leaf(self.proto)
-        with tracer.span(SPAN_RANGES):
-            urows, inv = np.unique(rows, return_inverse=True)
-            if urows[0] < 0 or urows[-1] >= self.meta["n_rows"]:
-                raise IndexError(
-                    f"take rows out of bounds for {self.meta['n_rows']}-row "
-                    "column")
-            c0, c1, rows_before = self._chunk_ranges_for_rows(urows)
-            n_chunks = len(rows_before)
-            # union of the [c0, c1] ranges via a coverage diff
-            # (O(chunks + rows))
-            cover = np.zeros(n_chunks + 1, dtype=np.int64)
-            np.add.at(cover, c0, 1)
-            np.add.at(cover, c1 + 1, -1)
-            needed = np.nonzero(np.cumsum(cover[:-1]) > 0)[0]
-            offs = np.asarray(self.meta["chunk_offsets"], dtype=np.int64)
-            sizes = np.array(
-                [self.meta["chunks"][c]["words"] * 8 for c in needed],
-                dtype=np.int64)
-
-        # IO: every needed chunk exactly once, one phase-0 batch dispatch
-        data, doffs = io.read_many(self.base + offs[needed], sizes, phase=0)
-        with tracer.span(SPAN_PARSE):
-            raws = [data[doffs[i]: doffs[i + 1]] for i in range(len(needed))]
-
-        # decode each chunk exactly once (numpy or batched pallas)
-        decoded = self._decode_chunks(needed, raws, tracer=tracer)
-        with tracer.span(SPAN_SELECT):
-            return self._select(rows_before, needed, urows, inv, decoded, io)
+            if plan.lookup is None:
+                return empty_leaf(self.proto)
+            with tracer.span(SPAN_SELECT):
+                rows_before, urows, inv = plan.lookup
+                return self._select(rows_before, plan.chunk_ids, urows, inv,
+                                    plan.decoded, io)
 
     def _select(self, rows_before, needed, urows, inv, decoded,
                 io) -> ShreddedLeaf:
@@ -419,24 +462,15 @@ class MiniBlockReader(ColumnReader):
         return reorder_leaf_rows(dec, inv)  # fan out to request order
 
     # ------------------------------------------------------------------
-    def _decode_chunks(self, chunk_ids, raws, tracer=None) -> List[tuple]:
-        """Decode chunks ``chunk_ids`` (raw payloads in ``raws``) exactly
-        once each.  Under ``decode='pallas'``, integer chunks (bit-packed or
-        FoR byte-packed values; flat, nested or fixed-size-list; any
-        rep/def level width) are batch-decoded by one ``pallas_call``; the
-        rest fall back to the numpy path per chunk.  ``tracer`` (the IO
-        path's, via the batch handle) receives a structured fallback-reason
-        event for every chunk that routes back to numpy."""
-        tracer = tracer or NULL_TRACER
-        if self.decode == "pallas":
-            routed = self._decode_chunks_pallas(chunk_ids, raws, tracer)
-            if routed is not None:
-                return routed
-        with tracer.span(SPAN_DECODE):
-            return [self._decode_chunk(c, raw)
-                    for c, raw in zip(chunk_ids, raws)]
-
     _PALLAS_MAX_TILE_VALUES = 1 << 17  # VMEM cap on tile_entries * vpe
+
+    @property
+    def kernel_class(self) -> tuple:
+        """The decode kernel's static shape class of this leaf:
+        ``(rep_bits, def_bits, vpe)``.  Leaves of one class share a launch."""
+        lt = self.proto.leaf_type
+        return (level_bits(self.proto.max_rep), level_bits(self.proto.max_def),
+                lt.size if isinstance(lt, T.FixedSizeList) else 1)
 
     def _pallas_eligible(self) -> bool:
         """Column-level kernel coverage: integer primitives and fixed-size
@@ -503,8 +537,15 @@ class MiniBlockReader(ColumnReader):
             return "ref-overflow"
         return f"opaque-codec:{codec}"
 
-    def _decode_chunks_pallas(self, chunk_ids, raws,
-                              tracer=NULL_TRACER) -> Optional[List[tuple]]:
+    def _kernel_params(self, chunk_ids,
+                       tracer=NULL_TRACER) -> Optional[List[Optional[tuple]]]:
+        """Each chunk's kernel ``(bits, ref)`` under ``decode='pallas'``
+        (None for a chunk the host decodes), or None when no chunk goes to
+        the kernel.  ``tracer`` receives a structured fallback-reason event
+        for every chunk that routes back to numpy.  Metadata only: no chunk
+        bytes are looked at."""
+        if self.decode != "pallas" or not len(chunk_ids):
+            return None
         note = tracer.enabled
         col_reason = self._pallas_ineligible_reason()
         if col_reason is not None:
@@ -512,104 +553,38 @@ class MiniBlockReader(ColumnReader):
                 tracer.fallback("miniblock", col_reason,
                                 n_chunks=len(chunk_ids))
             return None
-        import jax  # lazy: keep numpy-only readers jax-free
-
-        from ..kernels import ops
-        from ..kernels.miniblock_decode import MIN_TILE
-
-        lt = self.proto.leaf_type
-        fsl = isinstance(lt, T.FixedSizeList)
-        vpe = lt.size if fsl else 1
-        dt = np.dtype(lt.child.dtype if fsl else lt.dtype)
-        rep_bits = level_bits(self.proto.max_rep)
-        def_bits = level_bits(self.proto.max_def)
-        vbi = (1 if rep_bits else 0) + (1 if def_bits else 0)
-        metas = [self.meta["chunks"][c] for c in chunk_ids]
-        # metadata-only eligibility check first: chunks are parsed at most
-        # once, and an all-ineligible batch costs no parse work at all
-        kp = [self._chunk_kernel_params(cm["bufmeta"][vbi]) for cm in metas]
+        vbi = sum(1 for b in self.kernel_class[:2] if b)
+        metas = [self.meta["chunks"][c]["bufmeta"][vbi] for c in chunk_ids]
+        kp = [self._chunk_kernel_params(m) for m in metas]
         if note:
             reasons: Dict[str, int] = {}
-            for cm, p in zip(metas, kp):
+            for m, p in zip(metas, kp):
                 if p is None:
-                    r = self._chunk_fallback_reason(cm["bufmeta"][vbi])
+                    r = self._chunk_fallback_reason(m)
                     reasons[r] = reasons.get(r, 0) + 1
             for r in sorted(reasons):
                 tracer.fallback("miniblock", r, n_chunks=reasons[r])
-        if not any(p is not None for p in kp):
-            return None
-        sel = [i for i, p in enumerate(kp) if p is not None]
-        with tracer.span(SPAN_PARSE):
-            parsed = {i: _parse_chunk(raws[i]) for i in sel}
-        sp = ops.MINIBLOCK_DECODE
-        with tracer.span(sp.pack):
-            # power-of-two tiles of >= 1024 entries: whole (8, 128) vregs,
-            # and a handful of kernel shapes across batches
-            tile = max(MIN_TILE,
-                       ops.pow2(max(metas[i]["n_entries"] for i in sel)))
-            # chunk count and stream widths round up to powers of two
-            # (padding chunks decode to nothing), so batches share compiled
-            # shapes
-            n_pad = ops.pow2(len(sel))
-            params = np.zeros((n_pad, 3), dtype=np.int32)
-            streams = []  # (rep_words, def_words, val_words) ragged rows
-            for j, i in enumerate(sel):
-                cm, bufs = metas[i], parsed[i]
-                rw = ops.pack_words(bufs[0], pad_words=1) if rep_bits else None
-                dw = (ops.pack_words(bufs[1 if rep_bits else 0], pad_words=1)
-                      if def_bits else None)
-                vw = ops.pack_words(bufs[vbi], pad_words=1)
-                streams.append((rw, dw, vw))
-                params[j] = (cm["n_entries"], kp[i][0], kp[i][1])
+        return kp if any(p is not None for p in kp) else None
 
-            def stack(rows, active):
-                if not active:
-                    return np.zeros((n_pad, 1), dtype=np.uint32)
-                width = ops.pow2(max(len(r) for r in rows))
-                out = np.zeros((n_pad, width), dtype=np.uint32)
-                for j, r in enumerate(rows):
-                    out[j, : len(r)] = r
-                return out
-
-            stacked = [stack([s[0] for s in streams], rep_bits),
-                       stack([s[1] for s in streams], def_bits),
-                       stack([s[2] for s in streams], True)]
-        outs = ops.miniblock_decode(
-            *stacked, params, rep_bits=rep_bits, def_bits=def_bits, vpe=vpe,
-            tile_entries=tile, tracer=tracer)
-        with tracer.span(sp.wait):
-            outs = jax.block_until_ready(outs)
-        with tracer.span(sp.d2h):
-            rep_np, def_np, vals_np = (np.asarray(a) for a in outs)
-        if tracer.enabled:
-            sp.count(tracer,
-                     d2h=rep_np.nbytes + def_np.nbytes + vals_np.nbytes,
-                     true=_decode_true_bytes(params, def_np, rep_bits,
-                                             def_bits, vpe))
-
-        out: List[tuple] = [None] * len(chunk_ids)
-        with tracer.span(sp.unpack):
-            for j, i in enumerate(sel):
-                k = metas[i]["n_entries"]
-                rep = rep_np[j, :k].astype(np.uint8) if rep_bits else None
-                defs = def_np[j, :k].astype(np.uint8) if def_bits else None
-                n_valid = int((defs == 0).sum()) if defs is not None else k
-                dense = vals_np[j, : n_valid * vpe].astype(dt)
-                if fsl:
-                    vals = A.FixedSizeListArray(
-                        lt.with_nullable(False), np.ones(n_valid, bool),
-                        dense.reshape(n_valid, vpe),
-                    )
-                else:
-                    vals = A.PrimitiveArray(
-                        lt.with_nullable(False), np.ones(n_valid, bool),
-                        dense)
-                out[i] = (rep, defs, vals)
-        with tracer.span(SPAN_DECODE):
-            for i, p in enumerate(kp):
-                if p is None:
-                    out[i] = self._decode_chunk(chunk_ids[i], raws[i])
-        return out
+    def _from_kernel(self, rep_row, def_row, val_row, k: int) -> tuple:
+        """One chunk's ``(rep, defs, values)`` out of its rows of the
+        kernel's int32 outputs."""
+        rep_bits, def_bits, vpe = self.kernel_class
+        lt = self.proto.leaf_type
+        fsl = isinstance(lt, T.FixedSizeList)
+        dt = np.dtype(lt.child.dtype if fsl else lt.dtype)
+        rep = rep_row[:k].astype(np.uint8) if rep_bits else None
+        defs = def_row[:k].astype(np.uint8) if def_bits else None
+        n_valid = int((defs == 0).sum()) if defs is not None else k
+        dense = val_row[: n_valid * vpe].astype(dt)
+        if fsl:
+            vals = A.FixedSizeListArray(
+                lt.with_nullable(False), np.ones(n_valid, bool),
+                dense.reshape(n_valid, vpe))
+        else:
+            vals = A.PrimitiveArray(
+                lt.with_nullable(False), np.ones(n_valid, bool), dense)
+        return rep, defs, vals
 
     def scan(self, io, io_chunk: int = 8 << 20) -> ShreddedLeaf:
         offs = self.meta["chunk_offsets"]
@@ -619,15 +594,15 @@ class MiniBlockReader(ColumnReader):
             raw_parts.append(io.read(self.base + p, min(io_chunk, total - p), phase=0))
         raw = np.concatenate(raw_parts) if raw_parts else np.zeros(0, np.uint8)
         n_chunks = len(offs)
-        raws = [
-            raw[offs[ci]: offs[ci] + self.meta["chunks"][ci]["words"] * 8]
-            for ci in range(n_chunks)
-        ]
-        decoded = self._decode_chunks(np.arange(n_chunks), raws,
-                                      tracer=getattr(io, "tracer", None))
-        reps = [d[0] for d in decoded]
-        dfs = [d[1] for d in decoded]
-        vals = [d[2] for d in decoded]
+        ids = np.arange(n_chunks)
+        tracer = getattr(io, "tracer", NULL_TRACER)
+        plan = ChunkPlan(self, ids, [
+            _parse_chunk(raw[offs[ci]: offs[ci] + self.meta["chunks"][ci]["words"] * 8])
+            for ci in ids], self._kernel_params(ids, tracer))
+        decode_plans([plan], tracer)
+        reps = [d[0] for d in plan.decoded]
+        dfs = [d[1] for d in plan.decoded]
+        vals = [d[2] for d in plan.decoded]
         rep = np.concatenate(reps) if reps and reps[0] is not None else None
         defs = np.concatenate(dfs) if dfs and dfs[0] is not None else None
         if vals:
@@ -635,6 +610,110 @@ class MiniBlockReader(ColumnReader):
         else:
             values = empty_values(self.proto.leaf_type)
         return leaf_slice(self.proto, rep, defs, values, self.meta["n_rows"])
+
+
+def decode_plans(plans: List[ChunkPlan], tracer=NULL_TRACER) -> None:
+    """Decode every chunk of ``plans`` exactly once, filling each plan's
+    ``decoded``.
+
+    The kernel's chunks of all plans whose leaves share a static class
+    ``(rep_bits, def_bits, vpe)`` go to the device together, in one
+    ``ops.miniblock_decode`` launch per class; the other chunks are decoded
+    on the host.  One ``miniblock.decode_batch`` span holds it, and in it
+    one ``miniblock.take`` span (the leaf readers' work).  An enabled
+    ``tracer`` counts ``miniblock.decode_launches`` and
+    ``miniblock.chunks_decoded`` and observes each launch's leaf count in
+    the ``miniblock.leaves_per_launch`` histogram.
+    """
+    for p in plans:
+        p.decoded = [None] * len(p.chunk_ids)
+    plans = [p for p in plans if len(p.chunk_ids)]
+    if not plans:
+        return
+    with tracer.span(SPAN_DECODE_BATCH, n_leaves=len(plans)), \
+            tracer.span(SPAN_TAKE):
+        classes: Dict[tuple, List[ChunkPlan]] = {}
+        for p in plans:
+            if p.kernel is not None:
+                classes.setdefault(p.reader.kernel_class, []).append(p)
+        for cls, members in classes.items():
+            _decode_class(cls, members, tracer)
+        rest = [(p, i) for p in plans for i, d in enumerate(p.decoded)
+                if d is None]
+        if rest:
+            with tracer.span(SPAN_DECODE):
+                for p, i in rest:
+                    p.decoded[i] = p.reader._decode_chunk(p.chunk_ids[i],
+                                                          p.bufs[i])
+        if tracer.enabled:
+            tracer.count("miniblock.chunks_decoded",
+                         sum(len(p.chunk_ids) for p in plans))
+
+
+def _decode_class(cls: tuple, plans: List[ChunkPlan], tracer) -> None:
+    """One ``miniblock_decode`` launch over the kernel's chunks of
+    ``plans``, all of level class ``cls``."""
+    import jax  # lazy: keep numpy-only readers jax-free
+
+    from ..kernels import ops
+    from ..kernels.miniblock_decode import MIN_TILE
+
+    rep_bits, def_bits, vpe = cls
+    vbi = (1 if rep_bits else 0) + (1 if def_bits else 0)
+    jobs = [(p, i) for p in plans for i, k in enumerate(p.kernel)
+            if k is not None]
+    sp = ops.MINIBLOCK_DECODE
+    with tracer.span(sp.pack):
+        entries = [p.reader.meta["chunks"][p.chunk_ids[i]]["n_entries"]
+                   for p, i in jobs]
+        # power-of-two tiles of >= 1024 entries: whole (8, 128) vregs,
+        # and a handful of kernel shapes across batches
+        tile = max(MIN_TILE, ops.pow2(max(entries)))
+        # chunk count and stream widths round up to powers of two (padding
+        # chunks decode to nothing), so batches share compiled shapes
+        n_pad = ops.pow2(len(jobs))
+        params = np.zeros((n_pad, 3), dtype=np.int32)
+        streams = []  # (rep_words, def_words, val_words) ragged rows
+        for j, (p, i) in enumerate(jobs):
+            bufs = p.bufs[i]
+            rw = ops.pack_words(bufs[0], pad_words=1) if rep_bits else None
+            dw = (ops.pack_words(bufs[1 if rep_bits else 0], pad_words=1)
+                  if def_bits else None)
+            vw = ops.pack_words(bufs[vbi], pad_words=1)
+            streams.append((rw, dw, vw))
+            params[j] = (entries[j], *p.kernel[i])
+
+        def stack(rows, active):
+            if not active:
+                return np.zeros((n_pad, 1), dtype=np.uint32)
+            width = ops.pow2(max(len(r) for r in rows))
+            out = np.zeros((n_pad, width), dtype=np.uint32)
+            for j, r in enumerate(rows):
+                out[j, : len(r)] = r
+            return out
+
+        stacked = [stack([s[0] for s in streams], rep_bits),
+                   stack([s[1] for s in streams], def_bits),
+                   stack([s[2] for s in streams], True)]
+    outs = ops.miniblock_decode(
+        *stacked, params, rep_bits=rep_bits, def_bits=def_bits, vpe=vpe,
+        tile_entries=tile, tracer=tracer)
+    with tracer.span(sp.wait):
+        outs = jax.block_until_ready(outs)
+    with tracer.span(sp.d2h):
+        rep_np, def_np, vals_np = (np.asarray(a) for a in outs)
+    if tracer.enabled:
+        sp.count(tracer,
+                 d2h=rep_np.nbytes + def_np.nbytes + vals_np.nbytes,
+                 true=_decode_true_bytes(params, def_np, rep_bits,
+                                         def_bits, vpe))
+        tracer.count("miniblock.decode_launches")
+        tracer.metrics.histogram("miniblock.leaves_per_launch").observe(
+            len(plans))
+    with tracer.span(sp.unpack):
+        for j, (p, i) in enumerate(jobs):
+            p.decoded[i] = p.reader._from_kernel(rep_np[j], def_np[j],
+                                                 vals_np[j], entries[j])
 
 
 def _decode_true_bytes(params: np.ndarray, defs: np.ndarray, rep_bits: int,

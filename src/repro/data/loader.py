@@ -7,9 +7,9 @@ shuffles within a window, and prefetches batches on a background thread
 (host decode overlaps device step — the standard TPU input pipeline shape).
 
 The deterministic cursor (seed, step) -> batch makes restarts resume exactly
-(dist.fault.DataCursor); ``device_decode=True`` routes the final bit-unpack
-through the Pallas mini-block kernel instead of host numpy, demonstrating
-the HBM->VMEM decode path.
+(dist.fault.DataCursor).  Shuffled batches of whole rows by row id, decoded
+on the device, are ``repro.dataset.DatasetReader.take_columns`` under
+``decode="pallas"``.
 """
 
 from __future__ import annotations

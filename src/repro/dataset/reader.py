@@ -7,13 +7,16 @@ operation.  ``DatasetReader`` opens every fragment against **one** shared
 the dataset's concatenated global address space (see
 :mod:`repro.dataset.manifest`):
 
-* ``take(column, global_rows)`` vector-maps rows to fragments (searchsorted
-  over fragment row starts), fans out per-fragment batched leaf takes that
-  all enqueue into **one** scheduler batch — spans from different files
-  coalesce per dependency phase and the whole take is priced as a single
-  queue drain — then stitches the per-fragment leaves together and restores
-  request order with one shared
+* ``take_columns(columns, global_rows)`` vector-maps rows to fragments
+  (searchsorted over fragment row starts) once, fans out per-fragment
+  batched leaf takes of every column that all enqueue into **one**
+  scheduler batch — spans from different files and columns coalesce per
+  dependency phase and the whole take is priced as a single queue drain —
+  decodes the mini-block chunks of every leaf together (one kernel launch
+  per level class), then per column stitches the per-fragment leaves
+  together and restores request order with one shared
   :func:`~repro.core.encodings_base.reorder_leaf_rows` permutation;
+  ``take(column, global_rows)`` is ``take_columns`` of one column;
 * ``scan(column)`` streams every fragment through one prefetch-flagged
   batch, so ``SequentialReadahead`` sees a single global request stream and
   keeps reading ahead **across fragment boundaries** (the inter-file gap is
@@ -22,15 +25,17 @@ the dataset's concatenated global address space (see
   scan/take mix and auto-selects the admission policy of any cache level
   configured ``admission="auto"``.
 
-A take is one ``dataset.take:<column>`` span on the scheduler's tracer, with
-``dataset.locate`` (fragment routing and the per-fragment row sets), the leaf
-readers' spans, the batch's ``drain:*`` span and ``dataset.assemble``
-(stitching, request order and unshredding) inside it.
+A multi-column take is one ``dataset.take_columns`` span on the scheduler's
+tracer, with ``dataset.locate`` (fragment routing and the per-fragment row
+sets), the leaf readers' spans (the grouped ``miniblock.decode_batch``
+among them), the batch's ``drain:*`` span and ``dataset.assemble``
+(stitching, request order and unshredding) inside it; ``take`` wraps it in
+a ``dataset.take:<column>`` span.
 """
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -38,12 +43,14 @@ from ..core import arrays as A
 from ..core.encodings_base import concat_leaves, reorder_leaf_rows
 from ..core.file import FileReader, type_from_dict
 from ..core.io_sim import DiskView
+from ..core.miniblock import decode_plans
 from ..core.shred import unshred
 
 from .manifest import Manifest, build_dataset_disk
 
 __all__ = ["DatasetReader"]
 
+SPAN_TAKE_COLUMNS = "dataset.take_columns"
 SPAN_LOCATE = "dataset.locate"
 SPAN_ASSEMBLE = "dataset.assemble"
 
@@ -117,20 +124,34 @@ class DatasetReader:
 
     # -- public API ----------------------------------------------------------
     def take(self, name: str, rows) -> A.Array:
-        """Random access by *global* row ids (any order, duplicates fine).
+        """Random access by *global* row ids (any order, duplicates fine):
+        :meth:`take_columns` of one column, inside a
+        ``dataset.take:<column>`` span."""
+        rows = np.asarray(rows, dtype=np.int64)
+        with self.tracer.span(f"dataset.take:{name}", cat="reader",
+                              n_rows=len(rows)):
+            return self.take_columns([name], rows)[name]
 
-        One scheduler batch covers every fragment's reads, so per-phase
-        coalescing and queue-depth pricing see the union of all files'
-        spans; the result is bit-identical to running each fragment's take
-        separately and reassembling.
+    def take_columns(self, names: Sequence[str], rows) -> Dict[str, A.Array]:
+        """Whole rows by *global* row ids: ``{name: column}`` for every
+        column named, each in request order with duplicates, as
+        :meth:`take` returns it.
+
+        One ``locate`` routes the rows; one scheduler batch covers every
+        column's reads in every fragment, so per-phase coalescing and
+        queue-depth pricing see the union of all files' spans and the
+        request is one queue drain; the mini-block leaves of every column
+        and fragment are decoded together (:func:`decode_plans`: one kernel
+        launch per level class under ``decode="pallas"``); then each
+        column is assembled once.  The result is bit-identical to running
+        each fragment's take separately and reassembling.
         """
         rows = np.asarray(rows, dtype=np.int64)
-        col = self.columns[name]
-        if len(rows) == 0:
-            return self.fragments[0].take(name, rows)
+        names = list(dict.fromkeys(names))
+        cols = {n: self.columns[n] for n in names}
         tracer = self.tracer
-        with tracer.span(f"dataset.take:{name}", cat="reader",
-                         n_rows=len(rows)) as span:
+        with tracer.span(SPAN_TAKE_COLUMNS, cat="reader",
+                         n_columns=len(names), n_rows=len(rows)) as span:
             with tracer.span(SPAN_LOCATE):
                 fi, local = self.locate(rows)
                 # concat order = request rows stably grouped by fragment;
@@ -139,25 +160,27 @@ class DatasetReader:
                 perm = np.argsort(fi, kind="stable")
                 inv = np.empty(len(perm), dtype=np.int64)
                 inv[perm] = np.arange(len(perm), dtype=np.int64)
-                frag_ids = np.unique(fi)
+                # an empty request still reads fragment 0, for empty columns
+                frag_ids = np.unique(fi) if len(rows) else np.zeros(1, int)
                 local_rows = [local[fi == f] for f in frag_ids]
             span.set(n_fragments=len(frag_ids))
-            with self.scheduler.batch(f"take:{name}") as io:
+            label = (f"take:{names[0]}" if len(names) == 1
+                     else f"take:{len(names)}-columns")
+            with self.scheduler.batch(label) as io:
                 # the global rows are the logical requests this drain's
                 # modeled cost is attributed over (repro.obs.attrib)
                 io.note_requests(len(rows))
-                parts = [self.fragments[f].take_leaves(name, lr, io)
-                         for f, lr in zip(frag_ids, local_rows)]
+                plans: list = []  # every mini-block leaf's, decoded at once
+                frags = [self.fragments[f] for f in frag_ids]
+                parts = {n: [fr.take_leaves(n, lr, io, plans)
+                             for fr, lr in zip(frags, local_rows)]
+                         for n in names}
+                decode_plans(plans, tracer)
+                parts = {n: [fr.finish_leaves(p, io)
+                             for fr, p in zip(frags, parts[n])]
+                         for n in names}
             with tracer.span(SPAN_ASSEMBLE):
-                if col["kind"] in ("arrow", "packed"):
-                    return A.concat(parts).take(inv)
-                n_leaves = len(parts[0])
-                leaves = [
-                    reorder_leaf_rows(concat_leaves([p[k] for p in parts]),
-                                      inv)
-                    for k in range(n_leaves)
-                ]
-                return unshred(leaves, type_from_dict(col["type"]))
+                return {n: _assemble(cols[n], parts[n], inv) for n in names}
 
     def scan(self, name: str, io_chunk: int = 8 << 20) -> A.Array:
         """Full-column scan across all fragments, in global row order."""
@@ -197,3 +220,13 @@ class DatasetReader:
 
     def drop_caches(self) -> None:
         self.store.drop_caches()
+
+
+def _assemble(col: dict, parts: list, inv: np.ndarray) -> A.Array:
+    """One column of a take out of its per-fragment parts: stitched,
+    in request order, unshredded."""
+    if col["kind"] in ("arrow", "packed"):
+        return A.concat(parts).take(inv)
+    leaves = [reorder_leaf_rows(concat_leaves([p[k] for p in parts]), inv)
+              for k in range(len(parts[0]))]
+    return unshred(leaves, type_from_dict(col["type"]))

@@ -18,6 +18,10 @@ Coverage (static per call, constant per column):
   (``bitpack``), or byte-aligned FoR (``bytepack``, width*8 bits) with the
   per-chunk reference added back.
 
+The parameters reach the kernel's scalar memory as one flat ``(3 C,)``
+array: a ``(C, 3)`` one pads each row to 128 words there, and a call of a
+few thousand chunks (a take across many leaves) would not fit.
+
 Values come back in stream order (the i-th non-null value at slot i), which
 is what a reader assembles arrays from; placing them at entry positions is
 left to the caller.
@@ -49,10 +53,10 @@ NAME = "miniblock_decode"  # the kernel's name and its ops' named scope
 def _kernel(params_ref, *refs, rep_bits: int, def_bits: int, vpe: int):
     n_in = 1 + bool(rep_bits) + bool(def_bits)
     ins, outs = refs[:n_in], refs[n_in:]
-    c = pl.program_id(0)
-    n = params_ref[c, 0]
-    bits = params_ref[c, 1].astype(jnp.uint32)
-    ref = params_ref[c, 2]
+    c = 3 * pl.program_id(0)
+    n = params_ref[c]
+    bits = params_ref[c + 1].astype(jnp.uint32)
+    ref = params_ref[c + 2]
 
     shape = ins[-1].shape[1:]
     rows = shape[0] // vpe
@@ -116,7 +120,7 @@ def miniblock_decode_pallas(
                        for s in streams],
             interpret=interpret,
             name=NAME,
-        )(params, *streams)
+        )(params.reshape(-1), *streams)
         levels = iter(o.reshape(C, tile_entries) for o in outs[:n_levels])
         zeros = jnp.zeros((C, tile_entries), jnp.int32)
         rep = next(levels) if rep_bits else zeros

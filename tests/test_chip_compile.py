@@ -62,6 +62,25 @@ def test_miniblock_decode_compiles(spec, rep_bits, def_bits, words):
         spec((C, words), jnp.uint32), spec((C, 3), jnp.int32))
 
 
+@pytest.mark.parametrize("C,rep_bits,def_bits,rep_words,def_words", [
+    (16384, 1, 2, 256, 512),  # the 26 nullable List<int32> columns
+    (8192, 0, 1, 1, 256),     # the 13 nullable int64 columns
+    (256, 0, 0, 1, 1),        # the not-null label
+])
+def test_miniblock_decode_compiles_for_a_row_take(spec, C, rep_bits, def_bits,
+                                                  rep_words, def_words):
+    """One launch per level class of a 1024-row take of every column of
+    the Criteo rows at 1M rows: thousands of chunks in one call, their
+    parameters in scalar memory."""
+    tile = miniblock_decode.MAX_ENTRIES
+    _compile(
+        lambda r, d, v, p: miniblock_decode.miniblock_decode_pallas(
+            r, d, v, p, rep_bits=rep_bits, def_bits=def_bits,
+            tile_entries=tile, interpret=False),
+        spec((C, rep_words), jnp.uint32), spec((C, def_words), jnp.uint32),
+        spec((C, tile), jnp.uint32), spec((C, 3), jnp.int32))
+
+
 @pytest.mark.parametrize("n_rows,n_take", [(4096, 3000), (32768, 32768)])
 def test_fullzip_gather_compiles(spec, n_rows, n_take):
     # 128-d float32 vectors: 512 value bytes + 4-byte control word per row,

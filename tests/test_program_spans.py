@@ -109,12 +109,14 @@ def test_kernel_spans_sit_in_their_callers_span(traced, kernel, step):
 
 @pytest.mark.parametrize("child,parents", [
     ("store.read", {"fullzip.take", "miniblock.take"}),
-    ("fullzip.take", {"dataset.take:embedding", "dataset.take:centroid"}),
-    ("miniblock.take", {"dataset.take:posting"}),
-    ("dataset.locate", {"dataset.take:embedding", "dataset.take:posting",
-                        "dataset.take:centroid"}),
+    ("fullzip.take", {"dataset.take_columns"}),
+    ("miniblock.take", {"dataset.take_columns", "miniblock.decode_batch"}),
+    ("dataset.locate", {"dataset.take_columns"}),
     ("dataset.take:embedding", {None, "serve.candidates", "serve.winners"}),
     ("serve.topk", {"search"}),
+    ("dataset.take_columns", {"dataset.take:embedding", "dataset.take:posting",
+                              "dataset.take:centroid"}),
+    ("miniblock.decode_batch", {"dataset.take_columns"}),
 ])
 def test_spans_nest_by_layer(traced, child, parents):
     tracer, _, _ = traced
